@@ -1,27 +1,29 @@
 # One-liners for the repo's tier-1 verification and benchmarks (README.md).
 PY ?= python
 export PYTHONPATH := src:$(PYTHONPATH)
-export JAX_PLATFORMS ?= cpu
+# Tests run on the CPU (Pallas kernels in interpret mode); benchmarks and the
+# quickstart run on whatever accelerator JAX finds.
+TEST := JAX_PLATFORMS=cpu $(PY) -m pytest -x -q
 
 .PHONY: test test-fast test-reorder test-kernels test-serve test-sharded bench-smoke bench bench-kernels bench-update bench-storage bench-serve bench-search bench-shard bench-summary quickstart
 
 test:            ## tier-1: full test suite, stop at first failure (~2.5 min)
-	$(PY) -m pytest -x -q
+	$(TEST)
 
 test-fast:       ## tier-1 minus the slow interpret-mode sweeps
-	$(PY) -m pytest -x -q -m "not slow"
+	$(TEST) -m "not slow"
 
 test-reorder:    ## permutation-invariance property tier (both kernel backends)
-	$(PY) -m pytest -x -q tests/test_reorder.py tests/test_codec_registry.py
+	$(TEST) tests/test_reorder.py tests/test_codec_registry.py
 
 test-kernels:    ## kernel conformance + backend-equivalence tier
-	$(PY) -m pytest -x -q tests/test_kernel_conformance.py tests/test_kernels.py tests/test_search.py
+	$(TEST) tests/test_kernel_conformance.py tests/test_kernels.py tests/test_search.py
 
 test-serve:      ## admission/serving tier: simulated-clock properties + hot swap + quota floors
-	$(PY) -m pytest -x -q tests/test_admission.py tests/test_serve_ann.py tests/test_snapshot.py tests/test_codec_registry.py
+	$(TEST) tests/test_admission.py tests/test_serve_ann.py tests/test_snapshot.py tests/test_codec_registry.py
 
 test-sharded:    ## mesh-scale sharding tier: 8/16/32-device merges + routing + hot swap
-	$(PY) -m pytest -x -q tests/test_sharded.py
+	$(TEST) tests/test_sharded.py
 
 bench-kernels:   ## ref-vs-pallas-vs-auto-tuned per op + e2e -> BENCH_kernels.json (+ autotune cache)
 	$(PY) -m benchmarks.bench_kernels

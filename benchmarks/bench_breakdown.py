@@ -8,6 +8,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.search.engine import (EngineConfig, T_DEC, T_EX, T_IO, T_PQ,
                                       search_colocated, search_decoupled)
 
@@ -51,4 +52,5 @@ def main(quiet=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

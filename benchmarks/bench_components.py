@@ -9,6 +9,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.index import recall_at_k
 from repro.core.search.engine import (EngineConfig, search_colocated,
                                       search_decoupled)
@@ -69,4 +70,5 @@ def main(quiet=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -20,6 +20,7 @@ import zlib
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.codec import elias_fano as ef, huffman, xor_delta
 from repro.core.codec import registry as codecs
 from repro.core.graph.vamana import build_vamana
@@ -132,4 +133,5 @@ def main(quiet=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

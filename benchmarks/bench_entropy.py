@@ -6,6 +6,7 @@ entropy — the structure the XOR-delta + Huffman pipeline exploits.
 """
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.codec.entropy import characterize
 
 from .common import csv, dataset
@@ -32,4 +33,5 @@ def main(quiet=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

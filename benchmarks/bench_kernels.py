@@ -34,6 +34,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.codec.elias_fano import encode_slot
 from repro.core.index import build_device_index, recall_at_k
 from repro.core.search.beam import SearchParams, search
@@ -248,4 +249,5 @@ def main(quiet=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

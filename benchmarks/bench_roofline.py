@@ -4,6 +4,7 @@ Reads results/dryrun/*.json (produced by repro.launch.dryrun) and emits one
 CSV row per (arch x shape) cell with the three terms, dominant bottleneck,
 and useful-FLOPs ratio. Run the dry-run sweep first.
 """
+from repro.compile_cache import enable_compile_cache
 from repro.launch.summarize import load_cells
 
 from .common import csv
@@ -31,4 +32,5 @@ def main(quiet=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -31,6 +31,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.index import device_index_from_artifacts, recall_at_k
 from repro.core.search.beam import SearchParams
 from repro.core.search.engine import (EngineConfig, search_colocated,
@@ -268,6 +269,7 @@ def main(quiet=False, batches=BATCH_SWEEP, smoke=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", default="1,8,32",
                     help="comma-separated serving bucket sizes to sweep")

@@ -29,6 +29,7 @@ import os
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.index import build_device_index
 from repro.core.search.beam import SearchParams
 from repro.data.synthetic import make_queries, make_vector_dataset
@@ -156,6 +157,7 @@ def main(quiet: bool = False, smoke: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small world for the CI gate (~40 s)")

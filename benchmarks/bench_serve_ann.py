@@ -18,6 +18,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.distributed.sharded_index import build_sharded_index
 from repro.core.index import recall_at_k
 from repro.core.search.beam import SearchParams, search_vmapped
@@ -124,6 +125,7 @@ def main(quiet=False, n=None, reps=2, n_queries=64, batches=BATCHES,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=None)
     ap.add_argument("--queries", type=int, default=64)

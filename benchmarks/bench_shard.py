@@ -33,6 +33,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.distributed.sharded_index import (build_router,
                                                   build_sharded_index,
                                                   merge_comm_rows)
@@ -199,6 +200,7 @@ def main(quiet=False, n=None, n_queries=64, shards=SHARDS):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=None)
     ap.add_argument("--queries", type=int, default=64)

@@ -38,6 +38,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.codec import elias_fano as ef
 from repro.core.codec import registry as codecs
 from repro.core.graph import reorder as reorderlib
@@ -249,4 +250,5 @@ def main(quiet=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

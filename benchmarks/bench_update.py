@@ -28,6 +28,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.graph.pq import encode_pq, train_pq
 from repro.core.graph.vamana import build_vamana
 from repro.core.storage.colocated import ColocatedStore
@@ -182,4 +183,5 @@ def main(quiet=False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

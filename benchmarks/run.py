@@ -131,6 +131,7 @@ def main() -> None:
                    bench_storage, bench_update)
     print("name,us_per_call,derived")
     t00 = time.time()
+    failed = []
     for mod in (bench_entropy, bench_storage, bench_components, bench_search,
                 bench_breakdown, bench_update, bench_compression,
                 bench_kernels, bench_roofline, bench_serve_ann, bench_serve,
@@ -139,16 +140,21 @@ def main() -> None:
         try:
             mod.main(quiet=True)
         except Exception as e:  # keep the harness going; report the failure
+            failed.append(mod.__name__)
             print(f"{mod.__name__},0.0,ERROR:{type(e).__name__}:{e}")
             traceback.print_exc(file=sys.stderr)
         print(f"# {mod.__name__} done in {time.time()-t0:.1f}s",
               file=sys.stderr)
     print(f"# total {time.time()-t00:.1f}s", file=sys.stderr)
     summarize()
+    if failed:
+        sys.exit(f"# {len(failed)} benchmark(s) failed: {', '.join(failed)}")
 
 
 if __name__ == '__main__':
     if "--summary" in sys.argv[1:]:
         summarize()
     else:
+        from repro.compile_cache import enable_compile_cache
+        enable_compile_cache()
         main()

@@ -45,7 +45,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..index import build_device_index
 from ..search.beam import (DeviceIndex, SearchParams, resolve_kernels,
@@ -307,9 +306,9 @@ def _sharded_fn(mesh, p: SearchParams, axis, merge: str = "hier",
 
     n_in = N_SHARD_FIELDS
     extra = (P(),) if routed else ()
-    return shard_map(local_search, mesh=mesh,
-                     in_specs=(P(axis),) * n_in + (P(),) + extra,
-                     out_specs=(P(), P()), check_rep=False)
+    return jax.shard_map(local_search, mesh=mesh,
+                         in_specs=(P(axis),) * n_in + (P(),) + extra,
+                         out_specs=(P(), P()), check_vma=False)
 
 
 def make_sharded_search(mesh, p: SearchParams, axis="data",

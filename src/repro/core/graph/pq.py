@@ -4,7 +4,7 @@ traversal without touching full-precision vectors.
 
 Pure numpy/jnp: k-means codebook training, encoding, and asymmetric distance
 computation (ADC) via per-query lookup tables. The TPU hot path lives in
-``repro.kernels.pq_adc`` (one-hot × LUT matmul on the MXU); ``adc_lookup_np``
+``repro.kernels.pq_adc`` (an exact table select in VMEM); ``adc_lookup_np``
 here is the semantics oracle.
 """
 from __future__ import annotations
@@ -29,6 +29,20 @@ class PQCodebook:
         return self.centroids.shape[1]
 
 
+def _sq_dists(sub: np.ndarray, cents: np.ndarray) -> np.ndarray:
+    """[n, dsub] x [K, dsub] -> [n, K] squared L2, accumulated over dsub
+    in place on one [n, K] buffer (no [n, K, dsub] temporary: ~6x faster
+    at 2^20 rows). For dsub < 8 the sum order is numpy's own, so the
+    result equals ``((sub[:, None] - cents[None]) ** 2).sum(-1)`` exactly."""
+    acc = sub[:, None, 0] - cents[None, :, 0]
+    acc *= acc
+    for j in range(1, sub.shape[1]):
+        t = sub[:, None, j] - cents[None, :, j]
+        t *= t
+        acc += t
+    return acc
+
+
 def train_pq(vectors: np.ndarray, m: int = 8, k: int = 256, iters: int = 8,
              seed: int = 0, sample: int = 20_000) -> PQCodebook:
     """Train M sub-codebooks of K centroids by Lloyd's k-means."""
@@ -47,8 +61,7 @@ def train_pq(vectors: np.ndarray, m: int = 8, k: int = 256, iters: int = 8,
         sub = x[:, mi * dsub:(mi + 1) * dsub]
         c = sub[rng.choice(n, size=k_eff, replace=False)].copy()
         for _ in range(iters):
-            d2 = ((sub[:, None, :] - c[None, :, :]) ** 2).sum(-1)
-            assign = d2.argmin(1)
+            assign = _sq_dists(sub, c).argmin(1)
             for ci in range(k_eff):
                 mask = assign == ci
                 if mask.any():
@@ -59,7 +72,7 @@ def train_pq(vectors: np.ndarray, m: int = 8, k: int = 256, iters: int = 8,
     return PQCodebook(centroids=cents, dim=d)
 
 
-def encode_pq(vectors: np.ndarray, cb: PQCodebook, chunk: int = 4096) -> np.ndarray:
+def encode_pq(vectors: np.ndarray, cb: PQCodebook, chunk: int = 512) -> np.ndarray:
     """Encode [n, d] -> [n, M] uint8 codes."""
     x = np.asarray(vectors, dtype=np.float32)
     n, d = x.shape
@@ -69,8 +82,8 @@ def encode_pq(vectors: np.ndarray, cb: PQCodebook, chunk: int = 4096) -> np.ndar
         xi = x[i:i + chunk]
         for mi in range(m):
             sub = xi[:, mi * dsub:(mi + 1) * dsub]
-            d2 = ((sub[:, None, :] - cb.centroids[mi][None, :, :]) ** 2).sum(-1)
-            codes[i:i + chunk, mi] = d2.argmin(1).astype(np.uint8)
+            codes[i:i + chunk, mi] = _sq_dists(
+                sub, cb.centroids[mi]).argmin(1).astype(np.uint8)
     return codes
 
 
@@ -89,14 +102,15 @@ def adc_lookup_np(codes: np.ndarray, lut: np.ndarray) -> np.ndarray:
 
 
 def build_lut_jnp(query: jnp.ndarray, centroids: jnp.ndarray) -> jnp.ndarray:
-    """jnp LUT builder (device path). centroids [M, K, dsub]."""
+    """jnp LUT builder (device path). centroids [M, K, dsub].
+
+    The dsub squares are added left to right as explicit adds, which XLA
+    does not reorder: a search program computes the same LUT bits whatever
+    it fuses the LUT into, so programs that differ only in kernel backend
+    score identical distances."""
     m, k, dsub = centroids.shape
-    qs = query.reshape(m, 1, dsub)
-    return ((qs - centroids) ** 2).sum(-1)
-
-
-def adc_lookup_jnp(codes: jnp.ndarray, lut: jnp.ndarray) -> jnp.ndarray:
-    """jnp ADC via take_along_axis (XLA gather path; kernel does one-hot MXU)."""
-    m = lut.shape[0]
-    g = lut[jnp.arange(m)[None, :], codes.astype(jnp.int32)]
-    return g.sum(-1)
+    sq = (query.reshape(m, 1, dsub) - centroids) ** 2
+    acc = sq[..., 0]
+    for j in range(1, dsub):
+        acc = acc + sq[..., j]
+    return acc

@@ -109,17 +109,26 @@ def robust_prune(p: int, cand_ids: np.ndarray, vectors: np.ndarray,
     return np.asarray(result, np.int32)
 
 
+def medoid_of(vectors: np.ndarray) -> int:
+    """The vertex nearest the centroid: every search's entry point."""
+    return int(_l2(vectors, vectors.mean(0, keepdims=True)).argmin())
+
+
+def random_start(n: int, r: int, rng) -> list:
+    """The random regular graph a Vamana build starts from: min(r, n-1)
+    distinct random out-neighbors per vertex, self-loops dropped."""
+    adjacency = [rng.choice(n, size=min(r, n - 1), replace=False).astype(np.int32)
+                 for _ in range(n)]
+    return [a[a != i] for i, a in enumerate(adjacency)]
+
+
 def build_vamana(vectors: np.ndarray, r: int = 32, l_build: int = 64,
                  alpha: float = 1.2, seed: int = 0) -> VamanaGraph:
     vectors = np.asarray(vectors, dtype=np.float32)
     n = len(vectors)
     rng = np.random.default_rng(seed)
-    medoid = int(_l2(vectors, vectors.mean(0, keepdims=True)).argmin())
-    # Random regular start.
-    adjacency = [rng.choice(n, size=min(r, n - 1), replace=False).astype(np.int32)
-                 for _ in range(n)]
-    for i in range(n):
-        adjacency[i] = adjacency[i][adjacency[i] != i]
+    medoid = medoid_of(vectors)
+    adjacency = random_start(n, r, rng)
     for pass_alpha in (1.0, alpha):
         for i in rng.permutation(n):
             visited, _ = greedy_search(vectors, adjacency, medoid, vectors[i], l_build)

@@ -161,7 +161,7 @@ def _gather_neighbors(index: DeviceIndex, sel_ids: jnp.ndarray,
 def _adc_batch(codes: jnp.ndarray, luts: jnp.ndarray,
                kernels: KernelConfig | None) -> jnp.ndarray:
     """[nq, m, M] codes x [nq, M, K] per-query LUTs -> [nq, m] distances
-    (the batched pq_adc op: jnp gather-sum or one-hot × LUT MXU matmul)."""
+    (the batched pq_adc op: jnp gather-sum or the Pallas table select)."""
     return dispatch.pq_adc_batched(codes, luts, kernels)
 
 

@@ -36,9 +36,9 @@ T_IO_WRITE = 20.0    # µs per queued 4 KiB NVMe block write (merge path)
 
 # Per-backend compute costs (µs/op) for the latency model. "ref" prices the
 # paper's CPU implementation (the constants documented above); "pallas"
-# prices the TPU kernels (roofline estimate: ADC becomes a one-hot × LUT
-# matmul on the MXU, exact distances become MXU tiles, EF decode is VPU bit
-# ops — an order of magnitude under the scalar-CPU figures).
+# prices the TPU kernels (an estimate, not a chip measurement: ADC becomes
+# an in-VMEM table select, exact distances become MXU tiles, EF decode is
+# VPU bit ops — an order of magnitude under the scalar-CPU figures).
 # "pallas-interpret" is a *correctness* mode (the kernel run by the Pallas
 # interpreter on CPU) and is priced as ref so validation runs stay honest.
 KERNEL_COST_US = {
@@ -251,12 +251,15 @@ def merge_topk(ids, dists, k: int):
     """[S, nq, K] per-shard globally-translated ids + dists -> global top-K
     (host-side mirror of the gather + top_k merge that runs inside
     shard_map on a mesh; also merges the §3.5 memtable side-scan "shard"
-    with graph results). Stable sort: earlier shards win ties, and inf
-    distances (padding / tombstone-masked rows) sink to the tail."""
+    with graph results). Order is (distance, id), the tie-break of the
+    mesh merge (``sharded_index._lex_topk``), so the serial fan-in and the
+    shard_map program return the same ids even where integer-valued
+    corpora tie exactly; empty slots (-1, +inf) sink to the tail."""
     s, nq, kk = ids.shape
     flat_i = ids.transpose(1, 0, 2).reshape(nq, s * kk)
     flat_d = dists.transpose(1, 0, 2).reshape(nq, s * kk)
-    order = np.argsort(flat_d, axis=1, kind="stable")[:, :k]
+    tie = np.where(flat_i < 0, np.iinfo(np.int64).max, flat_i)
+    order = np.lexsort((tie, flat_d))[:, :k]
     return (np.take_along_axis(flat_i, order, 1),
             np.take_along_axis(flat_d, order, 1))
 

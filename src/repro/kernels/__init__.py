@@ -14,12 +14,13 @@ The search hot path (`core/search/beam.py`) threads the config through
 trace-time platform checks anywhere.
 
 Kernels (hot spots of the paper's search path, TPU-adapted per DESIGN.md §2):
-  pq_adc     — PQ asymmetric distance via one-hot × LUT matmul (MXU);
+  pq_adc     — PQ asymmetric distance via an exact in-VMEM table select;
                `pq_adc_batched` is the batched-queries entry the beam loop
                uses (grid over queries × row-blocks, per-query LUT resident)
   ef_decode  — Elias-Fano fixed-slot adjacency decode (VPU bit ops + rank)
   rerank_l2  — exact L2 re-ranking distances (MXU tiles)
   byteplane  — XOR-delta byte-plane decode of compressed vectors
+  beam_step  — the fused beam hop: ADC + top-L candidate merge
 """
 from . import byteplane, dispatch, ef_decode, pq_adc, rerank_l2  # noqa: F401
 from .dispatch import KernelConfig  # noqa: F401

@@ -16,7 +16,7 @@ measurement-driven one:
   so dispatch can never resolve to a bench-losing backend. Shapes are
   bucketed by rounding each dim up to a power of two; an unseen shape
   falls back to the nearest measured bucket of the same op (log-distance),
-  then to the op's gated ``auto`` rule when the op has no measurements at
+  then to the op's ``auto`` rule when the op has no measurements at
   all.
 
 Cache entries are platform-keyed: a cache measured on ``cpu`` (where the
@@ -97,7 +97,7 @@ class AutotuneCache:
              platform: str | None = None) -> "AutotuneCache":
         """Load the cache for ``platform``. A missing/unreadable file or a
         platform mismatch yields an EMPTY cache (resolution falls back to
-        the gated ``auto`` rules) — never an error and never cross-platform
+        the ``auto`` rule) — never an error and never cross-platform
         numbers."""
         if platform is None:
             import jax

@@ -21,15 +21,13 @@ Backends:
 Requested values additionally allow ``auto`` and ``auto-tuned``. Resolution
 (once, at config time):
 
-- ``auto`` -> ``pallas`` on TPU else ``ref`` — EXCEPT ops in ``AUTO_GATED``
-  (currently ``byteplane``, 452 vs 117 µs against ref on its own bench,
-  BENCH_kernels.json), which resolve to ``ref`` everywhere: auto must never
-  hand the hot path a backend that loses its own benchmark.
+- ``auto`` -> ``pallas`` on TPU else ``ref``, for every op: on the chip
+  the compiled kernels run, everywhere else the jnp oracle does.
 - ``auto-tuned`` -> the backend with the lowest MEASURED time for the
   (op, shape-bucket) in the persisted autotune cache (``autotune.py``,
   regenerated by ``benchmarks/bench_kernels.py``; ``REPRO_AUTOTUNE_CACHE``
-  overrides the path), falling back to the gated ``auto`` rule when the op
-  has no measurements on this platform. By construction it can never
+  overrides the path), falling back to the ``auto`` rule when the op has
+  no measurements on this platform. By construction it can never
   resolve to a backend that lost its bucket's bench.
 - ``pallas`` off-TPU degrades to ``pallas-interpret`` (a compiled Mosaic
   kernel only exists on TPU).
@@ -54,11 +52,6 @@ REQUESTED = BACKENDS + ("auto", "auto-tuned", "off")
 ENV_VAR = "REPRO_KERNELS"
 # The op list is KernelConfig._fields; the registry keys (which add the
 # batched pq_adc entry, keyed off the pq_adc config field) are authoritative.
-
-# Ops whose pallas kernel currently LOSES to ref on its own benchmark: the
-# uniform ``auto`` rule resolves these to ref on every platform until a
-# bench (via the autotune cache + ``auto-tuned``) shows otherwise.
-AUTO_GATED = frozenset({"byteplane"})
 
 
 class KernelConfig(NamedTuple):
@@ -109,9 +102,7 @@ class KernelConfig(NamedTuple):
         return all(b in BACKENDS + ("off",) for b in self)
 
 
-def _auto_rule(op: str | None, platform: str) -> str:
-    if op is not None and op in AUTO_GATED:
-        return "ref"
+def _auto_rule(platform: str) -> str:
     return "pallas" if platform == "tpu" else "ref"
 
 
@@ -120,9 +111,9 @@ def resolve_backend(requested: str, platform: str | None = None,
                     dims: dict | None = None) -> str:
     """One op's requested backend -> concrete backend for ``platform``.
 
-    ``op`` enables the per-op rules (AUTO_GATED, the autotune cache lookup,
-    the ``off`` pseudo-backend); without it the un-gated platform rule
-    applies. ``cache`` is an ``autotune.AutotuneCache`` (loaded lazily when
+    ``op`` enables the per-op rules (the autotune cache lookup, the
+    ``off`` pseudo-backend); without it only the platform rule applies.
+    ``cache`` is an ``autotune.AutotuneCache`` (loaded lazily when
     ``auto-tuned`` is requested and none is passed)."""
     if requested not in REQUESTED:
         raise ValueError(
@@ -138,12 +129,12 @@ def resolve_backend(requested: str, platform: str | None = None,
         return requested
     platform = platform or jax.default_backend()
     if requested == "auto":
-        return _auto_rule(op, platform)
+        return _auto_rule(platform)
     if requested == "auto-tuned":
         if cache is None:
             from . import autotune
             cache = autotune.AutotuneCache.load(platform=platform)
-        best = cache.best(op or "", dims, fallback=_auto_rule(op, platform))
+        best = cache.best(op or "", dims, fallback=_auto_rule(platform))
         # Degrade a measured 'pallas' pick off-TPU (it was measured as the
         # interpreter there — see autotune platform keying) and keep 'off'
         # as-is for beam_step.

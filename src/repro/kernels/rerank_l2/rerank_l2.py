@@ -1,16 +1,19 @@
 """Exact L2 re-ranking distances on the MXU.
 
-||q - x||^2 = ||q||^2 + ||x||^2 - 2 q.x : the cross term is a matmul, so the
-re-ranking phase (§3.4 phase 2) rides the systolic array instead of the VPU.
+||q - x||^2 = ||q||^2 + ||x||^2 - 2 q.x : the cross term and the candidate
+norms are matmuls, so the re-ranking phase (§3.4 phase 2) rides the
+systolic array instead of the VPU. Both run at ``Precision.HIGHEST``: the
+MXU's default single bf16 pass would round every float32 coordinate to 8
+bits and reorder near-tied candidates; at HIGHEST the products of
+integer-valued coordinates (uint8 / int8 corpora) are exact and their sums
+stay exact below 2^24, so the distances equal the oracle's bit for bit.
 
-Tiling: grid (Q-blocks, C-blocks); D is padded to a 128 multiple so tiles
-are MXU-aligned. Block sizes are CHOSEN PER SHAPE by the roofline tile
-planner (launch/roofline.py): the old fixed (BQ=8, BC=128) paid a measured
-cliff on non-tile-aligned candidate counts — q=32;c=130;d=64 padded 130 ->
-256 across 8 grid steps (1748 µs vs 308 ref, BENCH_kernels.json) — where
-the planner covers the same problem in ONE step (bq=32, bc=256) well under
-the VMEM budget. Per step VMEM holds q [bq, D], x [bq, bc, D], out
-[bq, bc]; the planner caps the working set at VMEM_TILE_BUDGET (8 MiB).
+Tiling: grid (queries, C-blocks); one query row [1, D] against a block of
+its candidates [BC, D] per step, written back as a lane-dense [1, BC] row
+of an [Q, 1, C] array (the TPU's (8, 128) tiling rule on the last two
+block dims). D is padded to a 128 multiple; C is padded to a multiple of 8
+and, up to C_WHOLE candidates, taken whole in one block, otherwise in
+blocks of BC_MAX.
 """
 import functools
 
@@ -18,47 +21,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.launch import roofline
-
-BQ = 8      # tile floors (the planner's smallest candidates)
-BC = 128
-
-
-@functools.lru_cache(maxsize=None)
-def _plan_tiles(qn: int, c: int, d: int) -> tuple[int, int]:
-    """(bq, bc) for a [qn, c, d] rerank: fewest grid steps, then least
-    padded work, subject to the per-step VMEM budget (roofline.choose_tile
-    on the candidate axis first — it sets the padded-work floor — then the
-    query axis given that choice). Static per shape: runs at trace time."""
-    dp = d + (-d) % 128
-    def vmem(bq, bc):
-        return (bq * dp + bq * bc * dp + bq * bc) * 4
-    bc = roofline.choose_tile(c, (BC, 256, 512, 1024),
-                              lambda t: vmem(BQ, t))
-    bq = roofline.choose_tile(qn, (BQ, 16, 32, 64),
-                              lambda t: vmem(t, bc))
-    return bq, bc
+C_WHOLE = 1024   # candidates per query taken in a single block
+BC_MAX = 512     # block size beyond that
 
 
 def _kernel(q_ref, x_ref, out_ref):
-    q = q_ref[...].astype(jnp.float32)               # [BQ, D]
-    x = x_ref[0].astype(jnp.float32)                 # [BC, D] (block of this q-row's cands)
-    qq = (q * q).sum(-1, keepdims=True)              # [BQ, 1]
-    xx = (x * x).sum(-1)                             # [BC]
-    cross = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    out_ref[...] = qq + xx[None, :] - 2.0 * cross
-
-
-def _kernel_grouped(q_ref, x_ref, out_ref):
-    # queries [BQ, D] with per-query candidate tiles [BQ, BC, D]
-    q = q_ref[...].astype(jnp.float32)
-    x = x_ref[...].astype(jnp.float32)
-    qq = (q * q).sum(-1, keepdims=True)
-    xx = (x * x).sum(-1)                              # [BQ, BC]
-    cross = jnp.einsum("qd,qcd->qc", q, x,
-                       preferred_element_type=jnp.float32)
-    out_ref[...] = qq + xx - 2.0 * cross
+    q = q_ref[0]                                          # [1, D]
+    x = x_ref[0]                                          # [BC, D]
+    nt = (((1,), (1,)), ((), ()))                         # contract D with D
+    mm = functools.partial(jax.lax.dot_general, dimension_numbers=nt,
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+    cross = mm(q, x)                                      # [1, BC]
+    xx = mm(jnp.ones_like(q), x * x)                      # [1, BC]
+    qq = jnp.sum(q * q, axis=1, keepdims=True)            # [1, 1]
+    out_ref[0] = qq + xx - 2.0 * cross
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -67,20 +44,22 @@ def rerank_l2_pallas(queries: jnp.ndarray, cands: jnp.ndarray,
     qn, d = queries.shape
     qn2, c, d2 = cands.shape
     assert qn == qn2 and d == d2
-    bq, bc = _plan_tiles(qn, c, d)
-    dp = (-d) % 128
-    qp, cp = (-qn) % bq, (-c) % bc
-    q_pad = jnp.pad(queries.astype(jnp.float32), ((0, qp), (0, dp)))
-    x_pad = jnp.pad(cands.astype(jnp.float32), ((0, qp), (0, cp), (0, dp)))
+    cp = c + (-c) % 8
+    bc = cp if cp <= C_WHOLE else BC_MAX
+    cp += (-cp) % bc
+    dp = d + (-d) % 128
+    q_pad = jnp.pad(queries.astype(jnp.float32), ((0, 0), (0, dp - d)))
+    x_pad = jnp.pad(cands.astype(jnp.float32),
+                    ((0, 0), (0, cp - c), (0, dp - d)))
     out = pl.pallas_call(
-        _kernel_grouped,
-        grid=((qn + qp) // bq, (c + cp) // bc),
+        _kernel,
+        grid=(qn, cp // bc),
         in_specs=[
-            pl.BlockSpec((bq, d + dp), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, bc, d + dp), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, dp), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, bc, dp), lambda i, j: (i, j, 0)),
         ],
-        out_specs=pl.BlockSpec((bq, bc), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((qn + qp, c + cp), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, bc), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((qn, 1, cp), jnp.float32),
         interpret=interpret,
-    )(q_pad, x_pad)
-    return out[:qn, :c]
+    )(q_pad[:, None], x_pad)
+    return out[:, 0, :c]

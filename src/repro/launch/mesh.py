@@ -10,6 +10,12 @@ from __future__ import annotations
 import jax
 
 
+def _auto(ndim: int) -> tuple:
+    # Auto axes: the model code places arrays with with_sharding_constraint,
+    # which rejects the Explicit axes jax.make_mesh creates by default.
+    return (jax.sharding.AxisType.Auto,) * ndim
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -20,13 +26,15 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices (set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count=512 before importing jax); have {len(devices)}")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(shape)),
+                         devices=devices[:n])
 
 
 def make_local_mesh(model_axis: int = 1):
     """Mesh over whatever devices exist (tests / single host)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def dp_size(mesh) -> int:

@@ -12,7 +12,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def quantize_int8(x):
@@ -63,7 +62,7 @@ def make_compressed_allreduce(mesh, axis_names=("data",)):
 
         @jax.jit
         def run(t, r):
-            return shard_map(
+            return jax.shard_map(
                 lambda tt, rr: compressed_psum_tree(tt, rr, axis_names),
                 mesh=mesh, in_specs=(spec, rspec), out_specs=(spec, rspec),
             )(t, r)

@@ -97,7 +97,8 @@ def test_multidevice_train_step_shards():
         from repro.optim.adamw import AdamWConfig, init_opt_state
         from repro.train.trainer import TrainConfig, make_train_step
         from repro.data.pipeline import TokenPipeline
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = reduce_config(get_config("internlm2-1.8b"), d_model=64)
         model = Model.from_config(cfg)
         with sharding.policy(mesh, None):

@@ -37,7 +37,8 @@ def test_lower_compile_all_phases_small_mesh():
         from repro.launch import roofline
         from repro.launch.dryrun import _rules_for, lower_full
         from repro.models.api import Model
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = reduce_config(get_config("jamba-v0.1-52b"))   # hybrid: hardest
         model = Model.from_config(cfg)
         result = {}

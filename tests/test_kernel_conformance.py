@@ -317,11 +317,10 @@ def test_resolution_rules():
 
 
 def test_auto_gating_rules():
-    """byteplane pallas loses its own bench (452 vs 117 µs): plain 'auto'
-    must resolve it to ref on EVERY platform, while ungated ops keep the
-    platform rule. 'off' is a fixed point for beam_step and an error
-    elsewhere."""
-    assert resolve_backend("auto", "tpu", op="byteplane") == "ref"
+    """No op is gated: plain 'auto' resolves EVERY op, byteplane included,
+    to compiled pallas on TPU and to ref elsewhere. 'off' is a fixed point
+    for beam_step and an error elsewhere."""
+    assert resolve_backend("auto", "tpu", op="byteplane") == "pallas"
     assert resolve_backend("auto", "cpu", op="byteplane") == "ref"
     assert resolve_backend("auto", "tpu", op="pq_adc") == "pallas"
     assert resolve_backend("auto", "tpu", op="beam_step") == "pallas"
@@ -330,7 +329,9 @@ def test_auto_gating_rules():
     with pytest.raises(ValueError, match="beam_step"):
         resolve_backend("off", "cpu", op="pq_adc")
     auto = KernelConfig().resolve("tpu")
-    assert auto.byteplane == "ref" and auto.pq_adc == "pallas"
+    assert auto == KernelConfig(*(["pallas"] * len(KernelConfig._fields)))
+    assert KernelConfig().resolve("cpu") == KernelConfig(
+        *(["ref"] * len(KernelConfig._fields)))
 
 
 def test_unresolved_auto_raises():
