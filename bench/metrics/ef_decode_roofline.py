@@ -1,0 +1,6 @@
+"""``ef_decode``'s share of its roofline (%)."""
+from bench.readers import roofline_share
+
+
+def read(run):
+    return roofline_share(run, "ef_decode")
