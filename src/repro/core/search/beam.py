@@ -408,12 +408,18 @@ def search_batched(index: DeviceIndex, queries: jnp.ndarray, p: SearchParams):
     """
     if p.kernels is None or not p.kernels.is_resolved:
         p = resolve_kernels(p)
-    luts = jax.vmap(
-        lambda q: build_lut_jnp(q.astype(jnp.float32), index.pq_centroids)
-    )(queries)
-    cand_ids, cand_d, (iters, fetched, pf_iter, pq_ct, trace, hints) = \
-        traverse(index, luts, p)
-    ids, dists, (batches, exact_ct) = rerank(index, queries, cand_ids, p)
+    # Named scopes are HLO metadata only: they tag each device op with its
+    # phase (the benchmark's trace reduction sums device time by them) and
+    # leave the compiled program as it was.
+    with jax.named_scope("beam.lut"):
+        luts = jax.vmap(
+            lambda q: build_lut_jnp(q.astype(jnp.float32), index.pq_centroids)
+        )(queries)
+    with jax.named_scope("beam.traverse"):
+        cand_ids, cand_d, (iters, fetched, pf_iter, pq_ct, trace, hints) = \
+            traverse(index, luts, p)
+    with jax.named_scope("beam.rerank"):
+        ids, dists, (batches, exact_ct) = rerank(index, queries, cand_ids, p)
     stats = SearchStats(iters, fetched, pf_iter, batches, exact_ct,
                         pq_ct, trace, hints)
     return ids, dists, stats

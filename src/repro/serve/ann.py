@@ -39,6 +39,7 @@ import time
 from dataclasses import dataclass, field
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core.codec import elias_fano as ef
@@ -117,6 +118,13 @@ class BatchReport:
     overlap_saved_us: float = 0.0   # blocking price of the same traversal
                                     # minus the overlapped price, summed
                                     # over queries; >= 0
+    # Traversal rounds, from SearchStats.iters of every launch (pad rows
+    # included): the lockstep while_loop runs max(iters) rounds per launch.
+    rounds: int = 0                 # sum over launches of max(iters)
+    row_rounds: int = 0             # sum over launched rows of iters
+    slot_rounds: int = 0            # sum over launches of max(iters) x bucket
+                                    # (row_rounds / slot_rounds: share of
+                                    # rows still active per round)
     modeled_latency_us: float = 0.0   # mean per-query modeled latency
     modeled_p99_us: float = 0.0
     snapshot_version: int = -1      # live mode: the snapshot pinned for this
@@ -325,6 +333,7 @@ class BatchedSearcher:
         # re-register with the same quotas.
         self._tenant_caches: dict = {}
         self._tenant_floors: dict = {}
+        self._calls = 0                # numbers each call's serve.search span
 
     # ------------------------------------------------------------ tenants
     def register_tenant(self, tenant: str, floor_bytes: int = 0) -> None:
@@ -393,7 +402,18 @@ class BatchedSearcher:
         crashes. With a router and ``ServeConfig(route_frac < 1)``, each
         query only searches (and is only charged I/O for) its routed
         shards.
+
+        Each call is a ``serve.search`` profiler span (``call``, ``nq``)
+        holding ``serve.plan``, then per launch ``serve.launch``
+        (``bucket``, ``count``), ``serve.fetch`` and ``serve.account``,
+        then ``serve.merge`` (docs/SERVING.md, "Tracing a server").
         """
+        self._calls += 1
+        with jax.profiler.TraceAnnotation("serve.search", call=self._calls,
+                                          nq=len(queries)):
+            return self._search(queries, tenants, failed_shards)
+
+    def _search(self, queries, tenants, failed_shards):
         queries = np.asarray(queries, np.float32)
         nq = len(queries)
         if tenants is not None and len(tenants) != nq:
@@ -451,17 +471,15 @@ class BatchedSearcher:
             for t in tenants:
                 report.tenants[t] = report.tenants.get(t, 0) + 1
         t0 = time.perf_counter()
-        chunks = plan_buckets(nq, self.cfg.buckets, self.cfg.max_chunks)
-        out_ids = np.full((n_lanes, nq, self.p.k), -1, np.int64)
-        out_d = np.full((n_lanes, nq, self.p.k), np.inf, np.float32)
-        lat = np.zeros((n_lanes, nq), np.float64)
+        with jax.profiler.TraceAnnotation("serve.plan"):
+            chunks = plan_buckets(nq, self.cfg.buckets, self.cfg.max_chunks)
+            out_ids = np.full((n_lanes, nq, self.p.k), -1, np.int64)
+            out_d = np.full((n_lanes, nq, self.p.k), np.inf, np.float32)
+            lat = np.zeros((n_lanes, nq), np.float64)
         for start, count, bucket in chunks:
             report.buckets.append(bucket)
             report.n_padded += bucket - count
-            q = queries[start:start + count]
-            if bucket > count:      # pad by repeating the last query
-                q = np.concatenate([q, np.repeat(q[-1:], bucket - count, 0)])
-            qj = jnp.asarray(q)
+            qj = None               # padded bucket, sent at first launch
             for si, shard in enumerate(shards):
                 if si in failed:
                     continue        # unresponsive: merge the rest
@@ -470,9 +488,22 @@ class BatchedSearcher:
                     active = route[start:start + count, si]
                     if not active.any():
                         continue    # no query routed here: zero I/O
-                ids, dists, stats = search(shard, qj, self.p)
-                ids = np.asarray(ids)[:count]
-                d = np.asarray(dists)[:count]
+                with jax.profiler.TraceAnnotation("serve.launch",
+                                                  bucket=bucket, count=count):
+                    if qj is None:
+                        q = queries[start:start + count]
+                        if bucket > count:  # pad by repeating the last query
+                            q = np.concatenate(
+                                [q, np.repeat(q[-1:], bucket - count, 0)])
+                        qj = jnp.asarray(q)
+                    ids, dists, stats = search(shard, qj, self.p)
+                with jax.profiler.TraceAnnotation("serve.fetch"):
+                    ids, d, iters = jax.device_get((ids, dists, stats.iters))
+                ids, d = ids[:count], d[:count]
+                most = int(iters.max())
+                report.rounds += most
+                report.row_rounds += int(iters.sum())
+                report.slot_rounds += most * bucket
                 if self._row_ids is not None:
                     # Frozen sharded: global ids through the shard's
                     # row_ids map; pad rows (row_id -1) are masked to
@@ -506,60 +537,67 @@ class BatchedSearcher:
                         caches = [self._caches[si]] * count
                         comps = [f"shard{si}"] * count
                         off = 0
-                    lat[si, start:start + count] = self._account(
-                        report, stats, count, caches, comps, key_offset=off,
-                        key_map=key_map, active=active)
-        if snap is not None:
-            # Memtable side-scan: buffered inserts are one more "shard" in
-            # the global merge (ids are globally unique fresh dense ids).
-            out_ids[-1], out_d[-1] = memtable_topk(
-                snap, queries, self.p.k, self.p.kernels)
-            report.mem_candidates = len(snap.mem_rows)
-        elif snaps is not None:
-            # One memtable lane per shard, local fresh ids translated by
-            # the handle's per-shard offset.
-            for si, s in enumerate(snaps):
-                if si in failed:
-                    continue
-                mids, md = memtable_topk(s, queries, self.p.k,
-                                         self.p.kernels)
-                out_ids[len(shards) + si] = np.where(
-                    mids >= 0, mids + offsets[si], -1)
-                out_d[len(shards) + si] = md
-                report.mem_candidates += len(s.mem_rows)
-        ids, dists = merge_topk(out_ids, out_d, self.p.k)
-        report.wall_s = time.perf_counter() - t0
-        report.qps = nq / max(report.wall_s, 1e-9)
-        if self.cfg.account_io:
-            per_q = lat.max(axis=0)     # shards fan out in parallel
-            report.shard_busy_us = [float(lat[si].sum())
-                                    for si in range(len(shards))]
-            report.modeled_latency_us = float(per_q.mean())
-            report.modeled_p99_us = float(np.percentile(per_q, 99))
-            report.per_query_latency_us = [float(v) for v in per_q]
-            # Per-component engine metrics: cumulative BlockStore stats
-            # (per-shard partitions; the updater's own components when a
-            # live snapshot's stores share an engine are reported there).
-            report.component_io = {n: s.snapshot() for n, s in
-                                   self.blocks.components.items()}
-            report.component_cache = self.blocks.cache_stats()["partitions"]
-            if self.cfg.prefetch_depth > 0:
-                report.prefetch_queues = self.blocks.prefetch_stats()
-        if snap is not None:
-            report.storage_bytes = dict(
-                adjacency=snap.index_store.physical_bytes,
-                adjacency_sparse_index=snap.index_store.sparse_index_bytes,
-                vector_chunks=snap.vector_store.physical_bytes,
-                vector_metadata=snap.vector_store.metadata_bytes)
-        elif snaps is not None:
-            report.storage_bytes = dict(
-                adjacency=sum(s.index_store.physical_bytes for s in snaps),
-                adjacency_sparse_index=sum(
-                    s.index_store.sparse_index_bytes for s in snaps),
-                vector_chunks=sum(
-                    s.vector_store.physical_bytes for s in snaps),
-                vector_metadata=sum(
-                    s.vector_store.metadata_bytes for s in snaps))
+                    with jax.profiler.TraceAnnotation("serve.account"):
+                        lat[si, start:start + count] = self._account(
+                            report, stats, count, caches, comps,
+                            key_offset=off, key_map=key_map, active=active)
+        with jax.profiler.TraceAnnotation("serve.merge"):
+            if snap is not None:
+                # Memtable side-scan: buffered inserts are one more "shard"
+                # in the global merge (ids are globally unique fresh dense
+                # ids).
+                out_ids[-1], out_d[-1] = memtable_topk(
+                    snap, queries, self.p.k, self.p.kernels)
+                report.mem_candidates = len(snap.mem_rows)
+            elif snaps is not None:
+                # One memtable lane per shard, local fresh ids translated by
+                # the handle's per-shard offset.
+                for si, s in enumerate(snaps):
+                    if si in failed:
+                        continue
+                    mids, md = memtable_topk(s, queries, self.p.k,
+                                             self.p.kernels)
+                    out_ids[len(shards) + si] = np.where(
+                        mids >= 0, mids + offsets[si], -1)
+                    out_d[len(shards) + si] = md
+                    report.mem_candidates += len(s.mem_rows)
+            ids, dists = merge_topk(out_ids, out_d, self.p.k)
+            report.wall_s = time.perf_counter() - t0
+            report.qps = nq / max(report.wall_s, 1e-9)
+            if self.cfg.account_io:
+                per_q = lat.max(axis=0)     # shards fan out in parallel
+                report.shard_busy_us = [float(lat[si].sum())
+                                        for si in range(len(shards))]
+                report.modeled_latency_us = float(per_q.mean())
+                report.modeled_p99_us = float(np.percentile(per_q, 99))
+                report.per_query_latency_us = [float(v) for v in per_q]
+                # Per-component engine metrics: cumulative BlockStore
+                # stats (per-shard partitions; the updater's own components
+                # when a live snapshot's stores share an engine are
+                # reported there).
+                report.component_io = {n: s.snapshot() for n, s in
+                                       self.blocks.components.items()}
+                report.component_cache = \
+                    self.blocks.cache_stats()["partitions"]
+                if self.cfg.prefetch_depth > 0:
+                    report.prefetch_queues = self.blocks.prefetch_stats()
+            if snap is not None:
+                store = snap.index_store
+                report.storage_bytes = dict(
+                    adjacency=store.physical_bytes,
+                    adjacency_sparse_index=store.sparse_index_bytes,
+                    vector_chunks=snap.vector_store.physical_bytes,
+                    vector_metadata=snap.vector_store.metadata_bytes)
+            elif snaps is not None:
+                report.storage_bytes = dict(
+                    adjacency=sum(
+                        s.index_store.physical_bytes for s in snaps),
+                    adjacency_sparse_index=sum(
+                        s.index_store.sparse_index_bytes for s in snaps),
+                    vector_chunks=sum(
+                        s.vector_store.physical_bytes for s in snaps),
+                    vector_metadata=sum(
+                        s.vector_store.metadata_bytes for s in snaps))
         return ids, dists, report
 
     # ------------------------------------------------------ I/O accounting

@@ -235,3 +235,67 @@ def test_live_searcher_hot_swaps_on_publish(live_world):
     assert target not in set(ids2.reshape(-1).tolist())
     assert fresh_id in set(ids2[0].tolist())    # now served from the graph
     assert rep2.mem_candidates == 0
+
+
+@pytest.mark.parametrize("nq", [1, 7, 12])
+def test_round_counters_match_iters(small_world, nq):
+    """rounds / row_rounds / slot_rounds are read off SearchStats.iters of
+    each launch, pad rows included: nq=7 pads one row, nq=12 runs as 8+1+
+    1+1+1."""
+    vecs, index, queries = small_world
+    p = _params(len(vecs))
+    searcher = BatchedSearcher(index, p, ServeConfig(buckets=(1, 8, 32)))
+    _, _, rep = searcher.search(queries[:nq])
+    rounds = row_rounds = slot_rounds = 0
+    for start, count, bucket in plan_buckets(nq, (1, 8, 32)):
+        q = queries[start:start + count]
+        q = np.concatenate([q, np.repeat(q[-1:], bucket - count, 0)])
+        iters = np.asarray(search(index, q, searcher.p)[2].iters)
+        rounds += int(iters.max())
+        row_rounds += int(iters.sum())
+        slot_rounds += int(iters.max()) * bucket
+    assert (rep.rounds, rep.row_rounds, rep.slot_rounds) == (
+        rounds, row_rounds, slot_rounds)
+    assert 0 < rep.row_rounds <= rep.slot_rounds
+    if nq == 1:
+        assert rep.row_rounds == rep.rounds == rep.slot_rounds
+    if nq == 7:     # the pad row repeats query 6: its rounds count again
+        real = np.asarray(search(index, queries[:7], searcher.p)[2].iters)
+        assert rep.row_rounds == int(real.sum()) + int(real[6])
+
+
+def test_search_spans_nest_in_the_callers_window(small_world, tmp_path):
+    """A CPU-recorded trace of one call holds ``serve.search`` around
+    ``serve.plan``, ``serve.launch``, ``serve.fetch``, ``serve.account`` and
+    ``serve.merge``, read with the caller's own span, on one clock."""
+    import jax
+
+    vecs, index, queries = small_world
+    searcher = BatchedSearcher(index, _params(len(vecs)),
+                               ServeConfig(buckets=(1, 8)))
+    searcher.search(queries[:9])                # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.window"):
+        searcher.search(queries[:9])            # runs as 8 + 1
+    jax.profiler.stop_trace()
+    from jax.profiler import ProfileData
+    path = sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))[-1]
+    spans = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("serve.", "bench.")):
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)))
+    window, = spans["bench.window"]
+    (s0, s1, meta), = spans["serve.search"]
+    assert meta == {"call": 2, "nq": 9}
+    assert window[0] <= s0 and s1 <= window[1]
+    for name, n in [("serve.plan", 1), ("serve.launch", 2),
+                    ("serve.fetch", 2), ("serve.account", 2),
+                    ("serve.merge", 1)]:
+        assert len(spans[name]) == n, name
+        assert all(s0 <= a and b <= s1 for a, b, _ in spans[name]), name
+    assert [m for *_, m in sorted(spans["serve.launch"])] == [
+        {"bucket": 8, "count": 8}, {"bucket": 1, "count": 1}]
