@@ -23,6 +23,8 @@ from __future__ import annotations
 from collections import OrderedDict
 from dataclasses import dataclass, field
 
+import numpy as np
+
 from .layout import BLOCK_SIZE
 
 __all__ = ["BLOCK_SIZE", "IOStats", "LRUCache", "SharedBudget",
@@ -181,6 +183,48 @@ class LRUCache:
             self._evict_oldest()
         if self.budget is not None:
             self.budget.rebalance()
+
+    def replay(self, keys: list) -> np.ndarray:
+        """Look up a stream of demand keys in arrival order, inserting each
+        miss: one hit flag per key, the same flags, counters and recency
+        order as ``get`` then ``put(key, True)`` on a miss, key by key.
+
+        Alone in its byte budget, the cache runs one tight pass over the
+        keys (bound methods, a local size); under a :class:`SharedBudget`
+        it keeps the per-key calls, since the pool's global-LRU eviction
+        reads every partition's recency ticks in this order."""
+        n = len(keys)
+        hit = np.zeros(n, bool)
+        if self.budget is not None:
+            for i, k in enumerate(keys):
+                if self.get(k) is not None:
+                    hit[i] = True
+                else:
+                    self.put(k, True)
+            return hit
+        self.lookups += n
+        if self.capacity <= 0:          # put stores nothing: every key misses
+            self.misses += n
+            return hit
+        d, cap = self._d, self.capacity
+        move, pop = d.move_to_end, d.popitem
+        size = len(d)
+        hits = []
+        note = hits.append
+        for i, k in enumerate(keys):
+            if k in d:
+                move(k)
+                note(i)
+            elif size < cap:
+                d[k] = True
+                size += 1
+            else:
+                d[k] = True
+                pop(False)
+        hit[hits] = True
+        self.hits += len(hits)
+        self.misses += n - len(hits)
+        return hit
 
     def _evict_oldest(self) -> None:
         key, _ = self._d.popitem(last=False)

@@ -46,11 +46,11 @@ from repro.core.codec import elias_fano as ef
 from repro.core.distributed.sharded_index import (ShardedIndex, ShardRouter,
                                                   route_mask)
 from repro.core.search.beam import (DeviceIndex, SearchParams,
-                                    resolve_kernels, search)
+                                    SearchStats, resolve_kernels, search)
 from repro.core.search.engine import (T_IO, beam_compute_costs,
                                       compute_costs, manifest_dec_costs,
                                       merge_topk, rerank_tail_us)
-from repro.core.storage.blockstore import BlockStore, LRUCache
+from repro.core.storage.blockstore import BLOCK_SIZE, BlockStore, LRUCache
 from repro.core.update.consistency import (ShardedSnapshotHandle,
                                            SnapshotHandle, memtable_topk)
 
@@ -108,6 +108,8 @@ class BatchReport:
     io_rounds: int = 0              # traversal rounds with >=1 STALLING read
                                     # (prefetch-covered rounds excluded)
     rerank_batches: int = 0
+    replay_rows_batched: int = 0    # rows whose fetch trace replayed in one
+                                    # LRU pass (speculative window off)
     # Speculative prefetch replay (ServeConfig.prefetch_depth > 0):
     prefetch_issued: int = 0        # speculative block reads issued
     prefetch_hits: int = 0          # speculations consumed by a demand fetch
@@ -334,6 +336,17 @@ class BatchedSearcher:
         self._tenant_caches: dict = {}
         self._tenant_floors: dict = {}
         self._calls = 0                # numbers each call's serve.search span
+        # The SearchStats leaves each launch copies back in serve.fetch, in
+        # the one blocking copy with ids and dists: the round counters, and
+        # with account_io the replay's inputs (hints with prefetch on).
+        fetched = {"iters"}
+        if cfg.account_io:
+            fetched |= {"fetch_trace", "pq_dists", "exact_dists",
+                        "rerank_batches"}
+            if cfg.prefetch_depth > 0:
+                fetched.add("hint_trace")
+        self._unfetched = {f: None for f in SearchStats._fields
+                           if f not in fetched}
 
     # ------------------------------------------------------------ tenants
     def register_tenant(self, tenant: str, floor_bytes: int = 0) -> None:
@@ -498,7 +511,9 @@ class BatchedSearcher:
                         qj = jnp.asarray(q)
                     ids, dists, stats = search(shard, qj, self.p)
                 with jax.profiler.TraceAnnotation("serve.fetch"):
-                    ids, d, iters = jax.device_get((ids, dists, stats.iters))
+                    ids, d, stats = jax.device_get(
+                        (ids, dists, stats._replace(**self._unfetched)))
+                iters = stats.iters
                 ids, d = ids[:count], d[:count]
                 most = int(iters.max())
                 report.rounds += most
@@ -615,7 +630,15 @@ class BatchedSearcher:
         without collisions. Rows with ``active[qi]`` false (the router
         skipped this shard for that query) are priced at zero — a
         non-routed shard does no I/O. Returns per-query modeled latency
-        [count] in µs."""
+        [count] in µs.
+
+        ``stats`` holds host arrays (``serve.fetch`` copied them). With the
+        speculative window off the replay is batched (:meth:`_replay`); on,
+        this walk interleaves speculative reads with demand reads round by
+        round, and that order is the model."""
+        if self.cfg.prefetch_depth == 0:
+            return self._replay(report, stats, count, caches, components,
+                                key_offset, key_map, active)
         trace = np.asarray(stats.fetch_trace)[:count]       # [c, iters, W]
         pq_ops = np.asarray(stats.pq_dists)[:count]
         exact = np.asarray(stats.exact_dists)[:count]
@@ -716,3 +739,55 @@ class BatchedSearcher:
             else:
                 lat[qi] = max(io, cpu) + min(io, cpu) * 0.1 + tail
         return lat
+
+    def _replay(self, report: BatchReport, stats, count: int, caches: list,
+                components: list, key_offset: int, key_map,
+                active) -> np.ndarray:
+        """:meth:`_account` with the speculative window off: the real
+        (non ``-1``) slots of the active rows are picked out and their keys
+        translated in NumPy, each row's keys go through one
+        ``LRUCache.replay`` pass (rows in arrival order), its misses are
+        charged as one block read of ``misses`` blocks, and the counters and
+        prices are NumPy sums and float64 expressions in the scalar walk's
+        order of operations: the same counts, LRU state and latencies."""
+        trace = stats.fetch_trace[:count]                   # [c, iters, W]
+        on = np.ones(count, bool) if active is None \
+            else np.asarray(active, bool)
+        real = (trace >= 0) & on[:, None, None]
+        row, rnd, _ = np.nonzero(real)                      # arrival order
+        vids = trace[real]
+        keys = (key_map[vids] if key_map is not None
+                else vids.astype(np.int64) + key_offset).tolist()
+        fetched = real.sum(axis=(1, 2))
+        ends = np.cumsum(fetched).tolist()
+        hit = np.zeros(len(keys), bool)
+        for qi in np.flatnonzero(on).tolist():
+            lo, hi = ends[qi] - int(fetched[qi]), ends[qi]
+            hit[lo:hi] = caches[qi].replay(keys[lo:hi])
+            misses = hi - lo - int(hit[lo:hi].sum())
+            if misses:
+                self.blocks.read(components[qi], nbytes=misses * BLOCK_SIZE,
+                                 n=misses)
+        hits = np.bincount(row[hit], minlength=count)
+        rounds = trace.shape[1]
+        stalled = np.zeros(count * rounds, bool)     # rounds with a miss
+        stalled[row[~hit] * rounds + rnd[~hit]] = True
+        io_rounds = stalled.reshape(count, rounds).sum(axis=1)
+        pq_ops = np.where(on, stats.pq_dists[:count], 0)
+        exact = np.where(on, stats.exact_dists[:count], 0)
+        batches = np.where(on, stats.rerank_batches[:count], 0)
+        dec_ix = fetched if self.p.use_ef else np.zeros(count, np.int64)
+        report.graph_ios += int((fetched - hits).sum())
+        report.cache_hits += int(hits.sum())
+        report.vector_ios += int(exact.sum())
+        report.pq_ops += int(pq_ops.sum())
+        report.exact_ops += int(exact.sum())
+        report.decompressions += int(dec_ix.sum() + exact.sum())
+        report.io_rounds += int(io_rounds.sum())
+        report.rerank_batches += int(batches.sum())
+        report.replay_rows_batched += int(on.sum())
+        io = io_rounds * T_IO
+        cpu = (pq_ops * self._t_pq + exact * self._t_ex
+               + dec_ix * self._t_dec_ix + exact * self._t_dec_vec)
+        tail = np.array([rerank_tail_us(b) for b in batches.tolist()])
+        return np.maximum(io, cpu) + np.minimum(io, cpu) * 0.1 + tail
