@@ -47,8 +47,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..index import build_device_index
-from ..search.beam import (DeviceIndex, SearchParams, resolve_kernels,
-                           search_batched)
+from ..search.beam import (DeviceIndex, SearchParams, SearchStats,
+                           resolve_kernels, search_batched)
 
 
 class ShardedIndex(NamedTuple):
@@ -262,10 +262,12 @@ def _merge_axis_tree(ids, d, name, size, k):
 
 
 def _sharded_fn(mesh, p: SearchParams, axis, merge: str = "hier",
-                routed: bool = False):
+                routed: bool = False, stats: tuple = ()):
     """The shard_map program: local beam search -> global-id translation
     (+ routing mask) -> hierarchical or flat merge. Returns a function of
-    (*ShardedIndex fields, queries[, route mask])."""
+    (*ShardedIndex fields, queries[, route mask]) -> (ids, dists), or, when
+    ``stats`` names ``SearchStats`` fields, (ids, dists, SearchStats) with
+    those fields stacked per shard on a leading axis and the rest None."""
     if merge not in ("hier", "flat"):
         raise ValueError(f"merge must be 'hier' or 'flat', got {merge!r}")
     # Config time: kernel backends are pinned BEFORE shard_map builds the
@@ -281,7 +283,7 @@ def _sharded_fn(mesh, p: SearchParams, axis, merge: str = "hier",
             neighbors=nbrs[0], counts=cnts[0], ef_slots=slots[0],
             pq_codes=codes[0], pq_centroids=cents[0], vectors=vecs[0],
             medoid=medoid[0])
-        ids, dists, _ = search_batched(local, queries, p)
+        ids, dists, st = search_batched(local, queries, p)
         # Global ids through the shard's row_ids map; pad rows (-1) and
         # empty result slots both land at (-1, +inf), so they can never
         # outrank a real candidate in any merge stage.
@@ -297,18 +299,23 @@ def _sharded_fn(mesh, p: SearchParams, axis, merge: str = "hier",
             d = jnp.where(mine[:, None], d, jnp.inf)
         # Innermost (intra-node) axis first: candidates are reduced to K
         # per node before any cross-node exchange.
-        for name, size in reversed(list(zip(names, sizes))):
-            if merge == "hier" and size & (size - 1) == 0:
-                gids, d = _merge_axis_tree(gids, d, name, size, p.k)
-            else:
-                gids, d = _merge_axis_flat(gids, d, name, p.k)
-        return gids, d
+        with jax.named_scope("shard.merge"):
+            for name, size in reversed(list(zip(names, sizes))):
+                if merge == "hier" and size & (size - 1) == 0:
+                    gids, d = _merge_axis_tree(gids, d, name, size, p.k)
+                else:
+                    gids, d = _merge_axis_flat(gids, d, name, p.k)
+        if not stats:
+            return gids, d
+        return gids, d, SearchStats(*(x[None] if f in stats else None
+                                      for f, x in zip(st._fields, st)))
 
     n_in = N_SHARD_FIELDS
     extra = (P(),) if routed else ()
+    out = (P(), P()) + ((P(axis),) if stats else ())
     return jax.shard_map(local_search, mesh=mesh,
                          in_specs=(P(axis),) * n_in + (P(),) + extra,
-                         out_specs=(P(), P()), check_vma=False)
+                         out_specs=out, check_vma=False)
 
 
 def make_sharded_search(mesh, p: SearchParams, axis="data",
@@ -337,9 +344,45 @@ def make_sharded_search(mesh, p: SearchParams, axis="data",
     return run
 
 
+def make_masked_search(mesh, p: SearchParams, axis="data",
+                       stats: tuple = ()):
+    """-> jit'd search(index: ShardedIndex, queries [Q, d], mask [Q, S]
+    bool) -> (ids, dists, SearchStats): the serving tier's bucket program,
+    with the hierarchical merge.
+
+    ``mask[q, s]`` false drops shard s's candidates for query q from the
+    merge (a failed shard is one no query is routed to); every shard still
+    searches every row, as the serial loop's routed launches do. The
+    ``stats`` fields come back stacked per shard (``[S, Q, ...]``)."""
+    fn = _sharded_fn(mesh, p, axis, routed=True, stats=stats)
+
+    @jax.jit
+    def run(index: ShardedIndex, queries, mask):
+        return fn(*index, queries, mask)
+    return run
+
+
 def place_on_mesh(index: ShardedIndex, mesh, axis="data") -> ShardedIndex:
     spec = NamedSharding(mesh, P(axis))
     return ShardedIndex(*(jax.device_put(x, spec) for x in index))
+
+
+def placed_mesh(index: ShardedIndex):
+    """(mesh, axis) when every field of ``index`` is laid out one shard per
+    device over a one-axis mesh, as ``place_on_mesh`` leaves it; else
+    None (one device, or any other layout)."""
+    sh = getattr(index.pq_codes, "sharding", None)
+    if not isinstance(sh, NamedSharding) or len(sh.mesh.axis_names) != 1:
+        return None
+    mesh, axis = sh.mesh, sh.mesh.axis_names[0]
+    if index.pq_codes.shape[0] < 2 or mesh.size != index.pq_codes.shape[0]:
+        return None
+    for x in index:
+        xs = getattr(x, "sharding", None)
+        if not (isinstance(xs, NamedSharding) and xs.mesh == mesh
+                and len(xs.spec) and xs.spec[0] == axis):
+            return None
+    return mesh, axis
 
 
 def lower_production_search(mesh, ann_cfg, p: SearchParams | None = None,

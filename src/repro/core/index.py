@@ -12,7 +12,7 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
-from .codec.elias_fano import encode_slot, slot_layout
+from .codec.elias_fano import encode_slots
 from .graph.pq import PQCodebook, encode_pq, train_pq
 from .graph.vamana import VamanaGraph, build_vamana
 from .search.beam import DeviceIndex
@@ -22,13 +22,13 @@ def ef_slots_from_graph(graph: VamanaGraph, universe: int | None = None
                         ) -> np.ndarray:
     """Encode every adjacency list (sorted ascending — search is
     order-independent, §3.2) into fixed-size EF slots."""
-    n = graph.n
-    universe = universe or n
-    _, _, _, words = slot_layout(graph.r, universe)
-    slots = np.zeros((n, words), dtype=np.uint32)
-    for i, adj in enumerate(graph.adjacency):
-        slots[i] = encode_slot(np.sort(adj.astype(np.uint64)), graph.r, universe)
-    return slots
+    universe = universe or graph.n
+    if any(len(a) > graph.r for a in graph.adjacency):
+        raise ValueError(f"an adjacency list is longer than r={graph.r}")
+    nbrs, counts = graph.to_padded()
+    # Pads sort last as universe - 1, which encode_slots writes for them.
+    nbrs = np.sort(np.where(nbrs < 0, universe - 1, nbrs), axis=1)
+    return encode_slots(nbrs, counts, graph.r, universe)
 
 
 def device_index_from_artifacts(vectors: np.ndarray, graph: VamanaGraph,

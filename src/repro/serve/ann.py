@@ -12,11 +12,13 @@ Three layers (docs/SERVING.md):
    the whole bucket, compare/reduce `top_k` selection — not scatter/sort,
    which is a scalar loop on XLA CPU).
 2. **Shard fan-out + global top-K merge.** A ``ShardedIndex``
-   (``core/distributed/sharded_index.py``) is searched shard-by-shard with
-   the same bucketed program (on a multi-device mesh the same merge runs
-   inside ``shard_map`` via ``make_sharded_search``); local ids are
-   translated by the shard's id-range offset and a global ``top_k`` over the
-   S*K gathered candidates yields the final K.
+   (``core/distributed/sharded_index.py``) laid out one shard per device
+   (``place_on_mesh``) is searched by one ``shard_map`` program per bucket:
+   every shard at once, ids translated through ``row_ids``, and the
+   butterfly merge on the devices (``make_masked_search``). On one device
+   it is searched shard-by-shard with the same bucketed program; local ids
+   are translated through ``row_ids`` and a global top-K over the S*K
+   gathered candidates yields the final K on the host.
 3. **Admission/stats.** Every served batch reports the paper's metrics
    (graph I/Os, vector I/Os, cache hits, modeled latency) by replaying the
    device fetch trace through the fixed-entry LRU of §3.4
@@ -41,10 +43,12 @@ from dataclasses import dataclass, field
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.codec import elias_fano as ef
 from repro.core.distributed.sharded_index import (ShardedIndex, ShardRouter,
-                                                  route_mask)
+                                                  make_masked_search,
+                                                  placed_mesh, route_mask)
 from repro.core.search.beam import (DeviceIndex, SearchParams,
                                     SearchStats, resolve_kernels, search)
 from repro.core.search.engine import (T_IO, beam_compute_costs,
@@ -127,6 +131,10 @@ class BatchReport:
     slot_rounds: int = 0            # sum over launches of max(iters) x bucket
                                     # (row_rounds / slot_rounds: share of
                                     # rows still active per round)
+    fanout_rounds: int = 0          # sum over buckets of the shards searched
+                                    # x the slowest one's rounds (rounds /
+                                    # fanout_rounds: share of chip-rounds
+                                    # not spent waiting at the merge)
     modeled_latency_us: float = 0.0   # mean per-query modeled latency
     modeled_p99_us: float = 0.0
     snapshot_version: int = -1      # live mode: the snapshot pinned for this
@@ -222,6 +230,16 @@ def plan_buckets(nq: int, buckets: tuple, max_chunks: int = 0) -> list:
     return out
 
 
+def _pad(queries: np.ndarray, start: int, count: int,
+         bucket: int) -> np.ndarray:
+    """Rows ``start:start + count``, padded to ``bucket`` by repeating the
+    last one."""
+    q = queries[start:start + count]
+    if bucket > count:
+        q = np.concatenate([q, np.repeat(q[-1:], bucket - count, 0)])
+    return q
+
+
 class BatchedSearcher:
     """Serve query batches against a DeviceIndex (1 shard), a ShardedIndex,
     or a live ``SnapshotHandle`` (§3.5 streaming index — hot-swapped on
@@ -278,6 +296,9 @@ class BatchedSearcher:
                                                     p.kernels.byteplane)
         self._row_ids = None           # frozen sharded: global-id maps
         self._key_maps = None          # frozen sharded: accounting keys
+        self._placed = None            # frozen sharded, one shard a device:
+                                       # (index, bucket program, replicated)
+        layout = None
         if self._handle is not None:
             self._shards = None        # resolved per batch (snapshot pin)
             self.shard_size = int(snap.device.pq_codes.shape[0])
@@ -288,10 +309,13 @@ class BatchedSearcher:
             n_caches = len(self._shandle)
         elif isinstance(index, ShardedIndex):
             s = index.pq_codes.shape[0]
+            # Laid out one shard per device: the placed arrays are served
+            # as they are, one shard_map program per bucket (built below).
+            layout = placed_mesh(index)
             # Named-field construction: ShardedIndex carries fields a
             # DeviceIndex does not (row_ids), so positional splatting
             # would silently land them in the tombstone slot.
-            self._shards = [
+            self._shards = None if layout is not None else [
                 DeviceIndex(neighbors=jnp.asarray(index.neighbors[i]),
                             counts=jnp.asarray(index.counts[i]),
                             ef_slots=jnp.asarray(index.ef_slots[i]),
@@ -347,6 +371,11 @@ class BatchedSearcher:
                 fetched.add("hint_trace")
         self._unfetched = {f: None for f in SearchStats._fields
                            if f not in fetched}
+        if layout is not None:
+            mesh, axis = layout
+            self._placed = (index, make_masked_search(
+                mesh, p, axis, stats=tuple(sorted(fetched))),
+                NamedSharding(mesh, P()))
 
     # ------------------------------------------------------------ tenants
     def register_tenant(self, tenant: str, floor_bytes: int = 0) -> None:
@@ -418,8 +447,9 @@ class BatchedSearcher:
 
         Each call is a ``serve.search`` profiler span (``call``, ``nq``)
         holding ``serve.plan``, then per launch ``serve.launch``
-        (``bucket``, ``count``), ``serve.fetch`` and ``serve.account``,
-        then ``serve.merge`` (docs/SERVING.md, "Tracing a server").
+        (``bucket``, ``count``; ``shards`` on the placed program's),
+        ``serve.fetch`` and per shard ``serve.account``, then
+        ``serve.merge`` (docs/SERVING.md, "Tracing a server").
         """
         self._calls += 1
         with jax.profiler.TraceAnnotation("serve.search", call=self._calls,
@@ -462,6 +492,8 @@ class BatchedSearcher:
             offsets = self._shandle.offsets
         else:
             shards = self._shards
+        n_shards = len(shards) if self._placed is None \
+            else int(self._placed[0].pq_codes.shape[0])
         failed = {int(s) for s in (failed_shards or ())}
         route = None
         if self._router is not None and self.cfg.route_frac < 1.0:
@@ -469,17 +501,18 @@ class BatchedSearcher:
                                           self.cfg.route_frac))
         mem_lanes = 1 if snap is not None else \
             (len(shards) if snaps is not None else 0)
-        n_lanes = len(shards) + mem_lanes
-        report = BatchReport(n_queries=nq, n_shards=len(shards),
+        # The placed program merges on the devices: one lane, merged.
+        n_lanes = (n_shards if self._placed is None else 1) + mem_lanes
+        report = BatchReport(n_queries=nq, n_shards=n_shards,
                              snapshot_version=snap.version if snap else -1,
                              failed_shards=sorted(failed))
         if snaps is not None:
             report.shard_versions = [s.version for s in snaps]
         if route is not None:
             report.routed_rows = int(route.sum())
-            report.fanout_frac = report.routed_rows / max(1, nq * len(shards))
+            report.fanout_frac = report.routed_rows / max(1, nq * n_shards)
         else:
-            report.routed_rows = nq * len(shards)
+            report.routed_rows = nq * n_shards
         if tenants is not None:
             for t in tenants:
                 report.tenants[t] = report.tenants.get(t, 0) + 1
@@ -488,11 +521,17 @@ class BatchedSearcher:
             chunks = plan_buckets(nq, self.cfg.buckets, self.cfg.max_chunks)
             out_ids = np.full((n_lanes, nq, self.p.k), -1, np.int64)
             out_d = np.full((n_lanes, nq, self.p.k), np.inf, np.float32)
-            lat = np.zeros((n_lanes, nq), np.float64)
+            lat = np.zeros((n_shards, nq), np.float64)
         for start, count, bucket in chunks:
             report.buckets.append(bucket)
             report.n_padded += bucket - count
+            if self._placed is not None:
+                self._launch_placed(report, queries, start, count, bucket,
+                                    failed, route, tenants, out_ids, out_d,
+                                    lat)
+                continue
             qj = None               # padded bucket, sent at first launch
+            searched = slowest = 0
             for si, shard in enumerate(shards):
                 if si in failed:
                     continue        # unresponsive: merge the rest
@@ -504,21 +543,12 @@ class BatchedSearcher:
                 with jax.profiler.TraceAnnotation("serve.launch",
                                                   bucket=bucket, count=count):
                     if qj is None:
-                        q = queries[start:start + count]
-                        if bucket > count:  # pad by repeating the last query
-                            q = np.concatenate(
-                                [q, np.repeat(q[-1:], bucket - count, 0)])
-                        qj = jnp.asarray(q)
+                        qj = jnp.asarray(_pad(queries, start, count, bucket))
                     ids, dists, stats = search(shard, qj, self.p)
                 with jax.profiler.TraceAnnotation("serve.fetch"):
                     ids, d, stats = jax.device_get(
                         (ids, dists, stats._replace(**self._unfetched)))
-                iters = stats.iters
                 ids, d = ids[:count], d[:count]
-                most = int(iters.max())
-                report.rounds += most
-                report.row_rounds += int(iters.sum())
-                report.slot_rounds += most * bucket
                 if self._row_ids is not None:
                     # Frozen sharded: global ids through the shard's
                     # row_ids map; pad rows (row_id -1) are masked to
@@ -537,25 +567,11 @@ class BatchedSearcher:
                                  np.inf).astype(np.float32)
                 out_ids[si, start:start + count] = gids
                 out_d[si, start:start + count] = d
-                if self.cfg.account_io:
-                    key_map = None
-                    if tenants is not None:
-                        rows = tenants[start:start + count]
-                        caches = [self._tenant_cache(t) for t in rows]
-                        comps = [f"tenant:{t}" for t in rows]
-                        if self._key_maps is not None:
-                            off, key_map = 0, self._key_maps[si]
-                        else:
-                            off = offsets[si] if offsets is not None \
-                                else si * self.shard_size
-                    else:
-                        caches = [self._caches[si]] * count
-                        comps = [f"shard{si}"] * count
-                        off = 0
-                    with jax.profiler.TraceAnnotation("serve.account"):
-                        lat[si, start:start + count] = self._account(
-                            report, stats, count, caches, comps,
-                            key_offset=off, key_map=key_map, active=active)
+                searched += 1
+                slowest = max(slowest, self._shard_done(
+                    report, si, stats, start, count, bucket, active, tenants,
+                    offsets, lat))
+            report.fanout_rounds += searched * slowest
         with jax.profiler.TraceAnnotation("serve.merge"):
             if snap is not None:
                 # Memtable side-scan: buffered inserts are one more "shard"
@@ -576,13 +592,15 @@ class BatchedSearcher:
                         mids >= 0, mids + offsets[si], -1)
                     out_d[len(shards) + si] = md
                     report.mem_candidates += len(s.mem_rows)
-            ids, dists = merge_topk(out_ids, out_d, self.p.k)
+            if self._placed is not None:
+                ids, dists = out_ids[0], out_d[0]
+            else:
+                ids, dists = merge_topk(out_ids, out_d, self.p.k)
             report.wall_s = time.perf_counter() - t0
             report.qps = nq / max(report.wall_s, 1e-9)
             if self.cfg.account_io:
                 per_q = lat.max(axis=0)     # shards fan out in parallel
-                report.shard_busy_us = [float(lat[si].sum())
-                                        for si in range(len(shards))]
+                report.shard_busy_us = [float(v) for v in lat.sum(axis=1)]
                 report.modeled_latency_us = float(per_q.mean())
                 report.modeled_p99_us = float(np.percentile(per_q, 99))
                 report.per_query_latency_us = [float(v) for v in per_q]
@@ -614,6 +632,77 @@ class BatchedSearcher:
                     vector_metadata=sum(
                         s.vector_store.metadata_bytes for s in snaps))
         return ids, dists, report
+
+    def _launch_placed(self, report, queries, start, count, bucket, failed,
+                       route, tenants, out_ids, out_d, lat) -> None:
+        """One bucket over a placed ShardedIndex: one launch of the
+        shard_map program (every shard at once, merge on the devices), one
+        fetch of the merged answers with every shard's stats, then each
+        searched shard's counters and replay as the serial loop runs them.
+        A failed shard is one no query is routed to."""
+        index, run, replicated = self._placed
+        n_shards = int(index.pq_codes.shape[0])
+        with jax.profiler.TraceAnnotation("serve.launch", bucket=bucket,
+                                          count=count, shards=n_shards):
+            mask = np.ones((bucket, n_shards), bool)
+            if route is not None:
+                mask[:count] = route[start:start + count]
+            mask[:, [s for s in failed if 0 <= s < n_shards]] = False
+            ids, dists, stats = run(
+                index,
+                jax.device_put(_pad(queries, start, count, bucket),
+                               replicated),
+                jax.device_put(mask, replicated))
+        with jax.profiler.TraceAnnotation("serve.fetch"):
+            ids, d, stats = jax.device_get((ids, dists, stats))
+        out_ids[0, start:start + count] = ids[:count]
+        out_d[0, start:start + count] = d[:count]
+        searched = slowest = 0
+        for si in range(n_shards):
+            if si in failed:
+                continue
+            active = None
+            if route is not None:
+                active = route[start:start + count, si]
+                if not active.any():
+                    continue
+            searched += 1
+            slowest = max(slowest, self._shard_done(
+                report, si, SearchStats(*(None if x is None else x[si]
+                                          for x in stats)),
+                start, count, bucket, active, tenants, None, lat))
+        report.fanout_rounds += searched * slowest
+
+    def _shard_done(self, report, si, stats, start, count, bucket, active,
+                    tenants, offsets, lat) -> int:
+        """Shard si's part of one bucket, from its fetched (host) stats: the
+        round counters, then its fetch traces replayed into its LRU
+        (``lat[si]``). Returns the rounds it ran."""
+        iters = stats.iters
+        most = int(iters.max())
+        report.rounds += most
+        report.row_rounds += int(iters.sum())
+        report.slot_rounds += most * bucket
+        if self.cfg.account_io:
+            key_map = None
+            if tenants is not None:
+                rows = tenants[start:start + count]
+                caches = [self._tenant_cache(t) for t in rows]
+                comps = [f"tenant:{t}" for t in rows]
+                if self._key_maps is not None:
+                    off, key_map = 0, self._key_maps[si]
+                else:
+                    off = offsets[si] if offsets is not None \
+                        else si * self.shard_size
+            else:
+                caches = [self._caches[si]] * count
+                comps = [f"shard{si}"] * count
+                off = 0
+            with jax.profiler.TraceAnnotation("serve.account"):
+                lat[si, start:start + count] = self._account(
+                    report, stats, count, caches, comps,
+                    key_offset=off, key_map=key_map, active=active)
+        return most
 
     # ------------------------------------------------------ I/O accounting
     def _account(self, report: BatchReport, stats, count: int,

@@ -152,3 +152,42 @@ def test_sharded_search_compiles_for_four_chips(one_chip):
     compiled = run.lower(index, q((NQ, D))).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "collective-permute" in text
+
+
+@pytest.mark.parametrize("bucket", [1, 8, 32])
+def test_served_sharded_bucket_compiles_for_four_chips(one_chip, bucket):
+    """The bucket program ``BatchedSearcher`` runs over a placed 4-shard
+    index at the ``bigann-share-4x`` cell's widths: R=128, 2^18 rows a
+    shard, EF slots at the universe 2^20, PQ m=32, L=200, W=4, a dense
+    visited set, the fetch trace and every shard's stats out, the merge on
+    the chips; its per-chip state fits one chip's HBM."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.codec.elias_fano import slot_layout
+    from repro.core.distributed.sharded_index import (ShardedIndex,
+                                                      make_masked_search)
+    from repro.core.search.beam import SearchParams
+    from repro.kernels.dispatch import KernelConfig
+    topo, _ = one_chip
+    mesh = jax.make_mesh((4,), ("data",), devices=topo.devices[:4])
+    r, per, universe = 128, 1 << 18, 1 << 20
+    s, q = _on(NamedSharding(mesh, P("data"))), _on(NamedSharding(mesh, P()))
+    index = ShardedIndex(
+        neighbors=s((4, per, r), jnp.int32), counts=s((4, per), jnp.int32),
+        ef_slots=s((4, per, slot_layout(r, universe)[3]), jnp.uint32),
+        pq_codes=s((4, per, M), jnp.uint8),
+        pq_centroids=s((4, M, KC, D // M)), vectors=s((4, per, D)),
+        medoid=s((4,), jnp.int32), row_ids=s((4, per), jnp.int32))
+    pallas = KernelConfig(*(["pallas"] * len(KernelConfig._fields)))
+    p = SearchParams(l_size=L, beam_width=W, k=K, rerank_batch=10, r_max=r,
+                     universe=universe, trace_fetches=True, kernels=pallas)
+    stats = ("exact_dists", "fetch_trace", "iters", "pq_dists",
+             "rerank_batches")
+    run = make_masked_search(mesh, p, stats=stats)
+    compiled = run.lower(index, q((bucket, D)),
+                         q((bucket, 4), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "collective-permute" in text
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < HBM_BYTES, used
