@@ -20,10 +20,14 @@ whole batch advances through ONE `while_loop` whose carries carry a leading
 query axis; finished rows are frozen by masking their updates. Single-query
 search is the nq=1 case (`search_one`). This is deliberately NOT
 `vmap(single_query_search)`: vmap of a `while_loop` re-selects every carry
-each round, which costs O(nq * n) on the dense visited arrays alone, while
-the hand-batched loop only touches what each round writes. The old vmapped
-formulation is kept as `search_vmapped` — it is the measured baseline that
-`benchmarks/bench_serve_ann.py` compares against.
+for every lane each round, while the hand-batched loop only touches what
+each round writes. The vmapped formulation is kept as `search_vmapped` — it
+is the baseline that `benchmarks/bench_serve_ann.py` compares against.
+
+The exact visited set is the candidate list itself: a round scores only the
+neighbors not already in the row's list of L, and each list slot carries an
+"expanded" flag. No carry has a dimension of n, so a hop costs O(W·R·L)
+whatever the shard size (see `traverse` for why this is exact).
 
 The uncompressed-adjacency variant exists for the paper's ablation (Exp#1
 "Decouple" / "DecoupleSearch" arms). The compute stages — batched PQ ADC,
@@ -79,8 +83,9 @@ class SearchParams(NamedTuple):
     use_ef: bool = True         # compressed index traversal
     r_max: int = 32
     universe: int = 0           # vector-id universe for EF slots (0 -> n)
-    visited_hash_bits: int = 0  # >0: open-addressing visited set of 2^bits
-                                # slots instead of [n]-bool arrays (§Perf B)
+    visited_hash_bits: int = 0  # >0: lossy open-addressing visited set of
+                                # 2^bits slots (§Perf B); 0: the exact set,
+                                # deduped against the candidate list
     trace_fetches: bool = False  # record the per-round adjacency-fetch ids so
                                  # the serving tier can replay them through
                                  # the §3.4 LRU / I/O model (serve/ann.py)
@@ -103,7 +108,13 @@ class SearchStats(NamedTuple):
     prefetch_iter: jnp.ndarray     # [nq] iteration prefetch triggered (-1: never)
     rerank_batches: jnp.ndarray    # [nq] re-rank batches actually executed
     exact_dists: jnp.ndarray       # [nq] full-precision distance computations
-    pq_dists: jnp.ndarray          # [nq] PQ (ADC) distance computations
+    pq_dists: jnp.ndarray          # [nq] PQ (ADC) distance computations:
+                                   # the entry plus every neighbor scored,
+                                   # an id the list dropped counting again
+                                   # each time it is scored again
+    list_hits: jnp.ndarray         # [nq] neighbors a round left unscored
+                                   # because they already sat in the row's
+                                   # candidate list (0 with the hashed set)
     fetch_trace: jnp.ndarray       # [nq, max_iters, W] fetched vertex ids
                                    # (-1 = none; empty unless trace_fetches)
     hint_trace: jnp.ndarray        # [nq, max_iters, W] provisional next-
@@ -167,7 +178,8 @@ def _adc_batch(codes: jnp.ndarray, luts: jnp.ndarray,
 
 def traverse(index: DeviceIndex, luts: jnp.ndarray, p: SearchParams):
     """Batched beam traversal: per-query LUTs [nq, M, K] ->
-    (cand_ids [nq, L], cand_d [nq, L], (iters, fetched, pf_iter, pq, trace)).
+    (cand_ids [nq, L], cand_d [nq, L],
+     (iters, fetched, pf_iter, pq, list_hits, trace, hints)).
 
     One while_loop advances the whole batch; a row with no unexpanded
     frontier (or out of iterations) is *frozen*: its frontier distances are
@@ -176,11 +188,22 @@ def traverse(index: DeviceIndex, luts: jnp.ndarray, p: SearchParams):
     therefore identical to what a solo (nq=1) run produces — the equality
     `tests/test_serve_ann.py` asserts.
 
-    Two visited-set representations (§Perf iteration B):
-    - dense [nq, n]-bool arrays (exact; O(n) HBM per query), or
-    - a 2^visited_hash_bits open-addressing fingerprint table plus
-      per-list-slot expansion flags (O(2^bits); a hash eviction can only
-      cause a re-visit — extra work, never a wrong result).
+    Each candidate-list slot carries an "expanded" flag, permuted through
+    the merge with the list. Two visited sets decide which of a round's
+    neighbors are scored:
+    - exact (``visited_hash_bits == 0``): a neighbor is scored unless it
+      already sits in the row's list. This gives the same lists, frontiers,
+      fetches and answers as a set of every id ever scored: an id leaves
+      the list (or fails to enter it) only when L entries of distance <=
+      its own are kept, the full list's worst distance never grows, and the
+      merge breaks ties toward the lower merged index with list entries
+      first, so an id scored again is dropped again. The entry, scored by
+      another op, is never scored again. Only ``pq_dists`` differs: it
+      counts those repeat scorings too. The set costs an [nq,
+      L] x [nq, W*R] compare a round, and no carry has a dimension of n.
+    - a 2^visited_hash_bits open-addressing fingerprint table (O(2^bits);
+      a hash eviction lets an id be scored again and, unlike the exact
+      set, enter the list a second time).
     """
     n = index.pq_codes.shape[0]
     nq = luts.shape[0]
@@ -195,31 +218,27 @@ def traverse(index: DeviceIndex, luts: jnp.ndarray, p: SearchParams):
     e_d = _adc_batch(index.pq_codes[entry][:, None, :], luts, p.kernels)[:, 0]
     cand_ids = jnp.full((nq, L), -1, jnp.int32).at[:, 0].set(entry)
     cand_d = jnp.full((nq, L), jnp.inf, jnp.float32).at[:, 0].set(e_d)
+    expanded = jnp.zeros((nq, L), jnp.bool_)        # per candidate slot
+    visited = None
     if use_hash:
         H = 1 << p.visited_hash_bits
         visited = jnp.full((nq, H), -1, jnp.int32
                            ).at[rows, _hash_slots(entry, p.visited_hash_bits)
                                 ].set(entry)
-        expanded = jnp.zeros((nq, L), jnp.bool_)    # per candidate slot
-    else:
-        visited = jnp.zeros((nq, n), jnp.bool_).at[rows, entry].set(True)
-        expanded = jnp.zeros((nq, n), jnp.bool_)
+    zeros = jnp.zeros((nq,), jnp.int32)
     state = (cand_ids, cand_d, visited, expanded,
-             jnp.zeros((nq,), jnp.int32),           # iters
-             jnp.zeros((nq,), jnp.int32),           # lists fetched
-             jnp.zeros((nq,), jnp.int32),           # pq distances (+ entry)
-             jnp.zeros((nq,), jnp.int32),           # stability counter
+             zeros,                                 # iters
+             zeros,                                 # lists fetched
+             zeros,                                 # pq distances (+ entry)
+             zeros,                                 # list hits
+             zeros,                                 # stability counter
              jnp.full((nq,), -1, jnp.int32),        # prefetch iteration
              jnp.full((nq, KB), -1, jnp.int32),     # prev top-(K+B)
              jnp.full((nq, trace_len, W), -1, jnp.int32),   # fetch trace
              jnp.full((nq, hint_len, W), -1, jnp.int32))    # hint trace
 
     def _unexpanded(cand_ids, expanded):
-        valid = cand_ids >= 0
-        if use_hash:
-            return valid & ~expanded
-        return valid & ~jnp.take_along_axis(
-            expanded, jnp.clip(cand_ids, 0, n - 1), 1)
+        return (cand_ids >= 0) & ~expanded
 
     def _active(cand_ids, expanded, iters):
         return (jnp.any(_unexpanded(cand_ids, expanded), 1)
@@ -230,7 +249,7 @@ def traverse(index: DeviceIndex, luts: jnp.ndarray, p: SearchParams):
         return jnp.any(_active(cand_ids, expanded, iters))
 
     def step(st):
-        (cand_ids, cand_d, visited, expanded, iters, fetched, pq_ct,
+        (cand_ids, cand_d, visited, expanded, iters, fetched, pq_ct, hit_ct,
          stab, pf_iter, prev_top, trace, hints) = st
         active = _active(cand_ids, expanded, iters)
         unexp = _unexpanded(cand_ids, expanded)
@@ -238,13 +257,8 @@ def traverse(index: DeviceIndex, luts: jnp.ndarray, p: SearchParams):
         neg_d, sel_slot = jax.lax.top_k(-frontier_d, W)       # [nq, W]
         sel_ids = jnp.where(jnp.isfinite(neg_d),
                             jnp.take_along_axis(cand_ids, sel_slot, 1), -1)
-        if use_hash:
-            expanded = expanded.at[rows[:, None], sel_slot].set(
-                jnp.take_along_axis(expanded, sel_slot, 1) | (sel_ids >= 0))
-        else:
-            expanded = expanded.at[
-                rows[:, None], jnp.where(sel_ids >= 0, sel_ids, n)].set(
-                True, mode="drop")
+        expanded = expanded.at[rows[:, None], sel_slot].set(
+            jnp.take_along_axis(expanded, sel_slot, 1) | (sel_ids >= 0))
         fetched = fetched + jnp.sum(sel_ids >= 0, 1).astype(jnp.int32)
         if p.trace_fetches:
             trace = trace.at[rows, iters].set(sel_ids, mode="drop")
@@ -278,10 +292,14 @@ def traverse(index: DeviceIndex, luts: jnp.ndarray, p: SearchParams):
             visited = visited.at[rows[:, None], jnp.where(ok, slots, H)].set(
                 jnp.where(ok, uniq, -1), mode="drop")
         else:
-            seen = jnp.take_along_axis(visited, jnp.clip(uniq, 0, n - 1), 1)
-            ok = (uniq >= 0) & ~seen
-            visited = visited.at[rows[:, None], jnp.where(ok, uniq, n)].set(
-                True, mode="drop")
+            # Dedupe against the row's list: [nq, L, W*R] compares, reduced
+            # over the list axis so the result keeps the neighbor axis. The
+            # entry is never scored again: its distance came from another
+            # op than the loop's, which may round it apart.
+            listed = jnp.any(cand_ids[:, :, None] == uniq[:, None, :], 1)
+            ok = (uniq >= 0) & ~listed & (uniq != entry[:, None])
+            hit_ct = hit_ct + jnp.sum((uniq >= 0) & listed,
+                                      1).astype(jnp.int32)
         new_ids = jnp.where(ok, uniq, -1)
         codes = index.pq_codes[jnp.clip(new_ids, 0, n - 1)]
         pq_ct = pq_ct + jnp.sum(ok, 1).astype(jnp.int32)
@@ -301,10 +319,9 @@ def traverse(index: DeviceIndex, luts: jnp.ndarray, p: SearchParams):
             top_d, top_i = jax.lax.top_k(-merged_d, L)
             cand_ids = jnp.take_along_axis(merged_ids, top_i, 1)
             cand_d = -top_d
-        if use_hash:
-            merged_exp = jnp.concatenate(
-                [expanded, jnp.zeros_like(new_ids, jnp.bool_)], 1)
-            expanded = jnp.take_along_axis(merged_exp, top_i, 1)
+        merged_exp = jnp.concatenate(
+            [expanded, jnp.zeros_like(new_ids, jnp.bool_)], 1)
+        expanded = jnp.take_along_axis(merged_exp, top_i, 1)
 
         # §3.4 stability: top-(K+B) id set unchanged across expansions.
         top_now = jnp.sort(cand_ids[:, :KB], 1)
@@ -315,13 +332,13 @@ def traverse(index: DeviceIndex, luts: jnp.ndarray, p: SearchParams):
         iters = iters + active.astype(jnp.int32)
         prev_top = jnp.where(active[:, None], top_now, prev_top)
         return (cand_ids, cand_d, visited, expanded, iters, fetched, pq_ct,
-                stab, pf_iter, prev_top, trace, hints)
+                hit_ct, stab, pf_iter, prev_top, trace, hints)
 
     st = jax.lax.while_loop(has_frontier, step, state)
     cand_ids, cand_d = st[0], st[1]
-    iters, fetched, pq_ct, _, pf_iter, _, trace, hints = st[4:]
-    return cand_ids, cand_d, (iters, fetched, pf_iter, pq_ct + 1, trace,
-                              hints)
+    iters, fetched, pq_ct, hit_ct, _, pf_iter, _, trace, hints = st[4:]
+    return cand_ids, cand_d, (iters, fetched, pf_iter, pq_ct + 1, hit_ct,
+                              trace, hints)
 
 
 def rerank(index: DeviceIndex, queries: jnp.ndarray, cand_ids: jnp.ndarray,
@@ -416,12 +433,12 @@ def search_batched(index: DeviceIndex, queries: jnp.ndarray, p: SearchParams):
             lambda q: build_lut_jnp(q.astype(jnp.float32), index.pq_centroids)
         )(queries)
     with jax.named_scope("beam.traverse"):
-        cand_ids, cand_d, (iters, fetched, pf_iter, pq_ct, trace, hints) = \
-            traverse(index, luts, p)
+        cand_ids, cand_d, (iters, fetched, pf_iter, pq_ct, hit_ct, trace,
+                           hints) = traverse(index, luts, p)
     with jax.named_scope("beam.rerank"):
         ids, dists, (batches, exact_ct) = rerank(index, queries, cand_ids, p)
     stats = SearchStats(iters, fetched, pf_iter, batches, exact_ct,
-                        pq_ct, trace, hints)
+                        pq_ct, hit_ct, trace, hints)
     return ids, dists, stats
 
 
@@ -481,9 +498,8 @@ def _search_vmapped_jit(index: DeviceIndex, queries: jnp.ndarray,
 def search_vmapped(index: DeviceIndex, queries: jnp.ndarray, p: SearchParams):
     """Legacy per-query vmap formulation (the pre-batching baseline).
 
-    vmap of a while_loop selects EVERY carry each round for every lane, so
-    this pays O(nq * n) visited/select traffic per round; kept for the
-    batched-vs-vmapped comparison in bench_serve_ann (~3x on XLA CPU,
-    growing with n).
+    vmap of a while_loop selects EVERY carry each round for every lane, and
+    runs every lane's body until the slowest lane finishes; kept for the
+    batched-vs-vmapped comparison in bench_serve_ann.
     """
     return _search_vmapped_jit(index, queries, resolve_kernels(p))

@@ -22,12 +22,13 @@ def beam_step_ref(codes: jnp.ndarray, luts: jnp.ndarray,
     luts     [nq, M, K] f32     per-query ADC lookup tables
     cand_ids [nq, L]    i32     current candidate list (-1 = empty slot)
     cand_d   [nq, L]    f32     current candidate PQ distances (+inf = empty)
-    new_ids  [nq, E]    i32     deduped, unvisited neighbor ids (-1 = masked)
+    new_ids  [nq, E]    i32     deduped neighbor ids not in the list
+                                (-1 = masked)
 
     Returns ``(cand_ids' [nq, L], cand_d' [nq, L], top_idx [nq, L])`` — the
     merged top-L by (distance, merged index) where merged = [cand | new];
     ``top_idx`` indexes that concatenation (callers use it to permute
-    side-car state such as the hash-visited ``expanded`` flags).
+    side-car state such as the per-slot ``expanded`` flags).
     """
     l_size = cand_ids.shape[1]
     d = pq_adc_batched_ref(codes, luts)

@@ -107,6 +107,8 @@ class BatchReport:
     vector_ios: int = 0             # full-precision vector block reads
     cache_hits: int = 0             # §3.4 fixed-entry LRU hits
     pq_ops: int = 0
+    list_hits: int = 0              # neighbors left unscored: already in
+                                    # the row's candidate list (SearchStats)
     exact_ops: int = 0
     decompressions: int = 0
     io_rounds: int = 0              # traversal rounds with >=1 STALLING read
@@ -365,8 +367,8 @@ class BatchedSearcher:
         # with account_io the replay's inputs (hints with prefetch on).
         fetched = {"iters"}
         if cfg.account_io:
-            fetched |= {"fetch_trace", "pq_dists", "exact_dists",
-                        "rerank_batches"}
+            fetched |= {"fetch_trace", "pq_dists", "list_hits",
+                        "exact_dists", "rerank_batches"}
             if cfg.prefetch_depth > 0:
                 fetched.add("hint_trace")
         self._unfetched = {f: None for f in SearchStats._fields
@@ -730,6 +732,7 @@ class BatchedSearcher:
                                 key_offset, key_map, active)
         trace = np.asarray(stats.fetch_trace)[:count]       # [c, iters, W]
         pq_ops = np.asarray(stats.pq_dists)[:count]
+        list_hits = np.asarray(stats.list_hits)[:count]
         exact = np.asarray(stats.exact_dists)[:count]
         batches = np.asarray(stats.rerank_batches)[:count]
         pf_on = self.cfg.prefetch_depth > 0
@@ -802,6 +805,7 @@ class BatchedSearcher:
             report.cache_hits += hits
             report.vector_ios += int(exact[qi])
             report.pq_ops += int(pq_ops[qi])
+            report.list_hits += int(list_hits[qi])
             report.exact_ops += int(exact[qi])
             report.decompressions += dec
             report.io_rounds += io_rounds
@@ -863,6 +867,7 @@ class BatchedSearcher:
         stalled[row[~hit] * rounds + rnd[~hit]] = True
         io_rounds = stalled.reshape(count, rounds).sum(axis=1)
         pq_ops = np.where(on, stats.pq_dists[:count], 0)
+        list_hits = np.where(on, stats.list_hits[:count], 0)
         exact = np.where(on, stats.exact_dists[:count], 0)
         batches = np.where(on, stats.rerank_batches[:count], 0)
         dec_ix = fetched if self.p.use_ef else np.zeros(count, np.int64)
@@ -870,6 +875,7 @@ class BatchedSearcher:
         report.cache_hits += int(hits.sum())
         report.vector_ios += int(exact.sum())
         report.pq_ops += int(pq_ops.sum())
+        report.list_hits += int(list_hits.sum())
         report.exact_ops += int(exact.sum())
         report.decompressions += int(dec_ix.sum() + exact.sum())
         report.io_rounds += int(io_rounds.sum())

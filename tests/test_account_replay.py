@@ -45,6 +45,7 @@ def per_key_account(searcher, blocks, report, stats, count, caches,
     one 4 KiB block read and a ``put``; each row priced in scalar Python."""
     trace = np.asarray(stats.fetch_trace)[:count]
     pq_ops = np.asarray(stats.pq_dists)[:count]
+    list_hits = np.asarray(stats.list_hits)[:count]
     exact = np.asarray(stats.exact_dists)[:count]
     batches = np.asarray(stats.rerank_batches)[:count]
     lat = np.zeros(count)
@@ -75,6 +76,7 @@ def per_key_account(searcher, blocks, report, stats, count, caches,
         report.cache_hits += hits
         report.vector_ios += int(exact[qi])
         report.pq_ops += int(pq_ops[qi])
+        report.list_hits += int(list_hits[qi])
         report.exact_ops += int(exact[qi])
         report.decompressions += dec_ix + dec_vec
         report.io_rounds += io_rounds
@@ -101,7 +103,8 @@ def _random_stats(rng, count, iters, n_ids, bucket):
     return SearchStats(iters=stop.astype(np.int32), lists_fetched=None,
                        prefetch_iter=None, rerank_batches=small(5),
                        exact_dists=small(60), pq_dists=small(900),
-                       fetch_trace=trace, hint_trace=None)
+                       list_hits=small(300), fetch_trace=trace,
+                       hint_trace=None)
 
 
 def _store(capacity, shared, floors):

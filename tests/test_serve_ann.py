@@ -165,6 +165,43 @@ def test_stats_disabled_path(small_world):
     np.testing.assert_array_equal(ids, np.asarray(ids_ref))
 
 
+@pytest.mark.parametrize("nq", [7, 32])
+def test_list_hits_sum_the_served_rows(small_world, nq):
+    """BatchReport.list_hits is the sum of SearchStats.list_hits over the
+    served rows (a pad row is left out), unsharded and through the serial
+    shard loop; the placed path is checked in test_sharded_serving."""
+    vecs, index, queries = small_world
+    p = _params(len(vecs))
+    searcher = BatchedSearcher(index, p, ServeConfig(buckets=(8, 32)))
+    _, _, rep = searcher.search(queries[:nq])
+    hits = np.asarray(search(index, queries[:nq], searcher.p)[2].list_hits)
+    assert rep.list_hits == int(hits.sum()) > 0
+
+    sh_index, per = build_sharded_index(vecs[:600], 2, r=16, l_build=32,
+                                        pq_m=4)
+    sh = BatchedSearcher(sh_index, _params(per), ServeConfig(buckets=(8, 32)),
+                         shard_size=per)
+    _, _, rep = sh.search(queries[:nq])
+    assert rep.n_shards == 2
+    want = sum(
+        int(np.asarray(search(type(index)(*(x[i] for x in sh_index[:7])),
+                              queries[:nq], sh.p)[2].list_hits).sum())
+        for i in range(2))
+    assert rep.list_hits == want > 0
+
+
+def test_list_hits_not_fetched_without_accounting(small_world):
+    vecs, index, queries = small_world
+    p = _params(len(vecs))
+    on = BatchedSearcher(index, p, ServeConfig(buckets=(8,)))
+    off = BatchedSearcher(index, p, ServeConfig(buckets=(8,),
+                                                account_io=False))
+    assert "list_hits" not in on._unfetched
+    assert "list_hits" in off._unfetched
+    assert off.search(queries[:8])[2].list_hits == 0
+    assert on.search(queries[:8])[2].list_hits > 0
+
+
 # --------------------------------------------------------------------------
 # Live-updatable serving: BatchedSearcher over a SnapshotHandle (§3.5)
 # --------------------------------------------------------------------------

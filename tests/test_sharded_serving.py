@@ -105,6 +105,12 @@ SCRIPT = textwrap.dedent("""
     result["rounds"] = {"rounds": rep.rounds, "fanout": rep.fanout_rounds,
                         "per_shard": per_shard, "one_rounds": r1.rounds,
                         "one_fanout": r1.fanout_rounds}
+    # The candidate-list hits of the same launch, per shard.
+    result["list_hits"] = [rep.list_hits, sum(
+        int(np.asarray(search(DeviceIndex(*(np.asarray(x[i])
+                                            for x in index[:7])),
+                              queries[:32], p)[2].list_hits).sum())
+        for i in range(S))]
 
     # Layouts that keep the loop: the stacked index on one device, and
     # four shards placed over a one-device mesh.
@@ -158,6 +164,11 @@ def test_fanout_rounds(served):
     assert r["rounds"] == sum(r["per_shard"])
     assert r["fanout"] == 4 * max(r["per_shard"])
     assert r["one_fanout"] == r["one_rounds"] > 0
+
+
+def test_placed_list_hits_sum_every_shard(served):
+    placed, per_shard = served["list_hits"]
+    assert placed == per_shard > 0
 
 
 def test_one_device_layouts_keep_the_serial_loop(served):

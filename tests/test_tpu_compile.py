@@ -126,6 +126,39 @@ def test_capacity_search_program_fits_hbm(one_chip):
     assert used < HBM_BYTES, used
 
 
+def test_exact_visited_set_program_holds_no_set_of_n(one_chip):
+    """The served search program with the exact visited set (the one every
+    benchmark cell runs) at the capacity widths: compiled Pallas, and every
+    array of the compiled program with a dimension of n is an index leaf's
+    (n leading, not bool). A dense visited set shows as ``pred[32,n]``,
+    ``pred[1,32,n]`` or ``pred[n]``."""
+    import re
+    from repro.core.codec.elias_fano import slot_layout
+    from repro.core.search.beam import DeviceIndex, SearchParams, \
+        search_batched
+    from repro.kernels.dispatch import KernelConfig
+    _, chip = one_chip
+    s = _on(chip)
+    r, n = WIDTHS["capacity"]
+    index = DeviceIndex(
+        neighbors=s((1, r), jnp.int32), counts=s((n,), jnp.int32),
+        ef_slots=s((n, slot_layout(r, n)[3]), jnp.uint32),
+        pq_codes=s((n, M), jnp.uint8), pq_centroids=s((M, KC, D // M)),
+        vectors=s((n, D)), medoid=s((), jnp.int32))
+    pallas = KernelConfig(*(["pallas"] * len(KernelConfig._fields)))
+    p = SearchParams(l_size=L, beam_width=W, k=K, rerank_batch=10, r_max=r,
+                     universe=n, trace_fetches=True, kernels=pallas)
+    text = _compile(lambda idx, q: search_batched(idx, q, p),
+                    index, s((NQ, D))).as_text()
+    assert text.count("tpu_custom_call") >= 4
+    with_n = [(dtype, dims) for dtype, dims in
+              re.findall(r"\b([a-z]+[0-9]*)\[([0-9,]+)\]", text)
+              if n in map(int, dims.split(","))]
+    assert with_n
+    for dtype, dims in with_n:
+        assert dims.startswith(f"{n}") and dtype != "pred", (dtype, dims)
+
+
 def test_sharded_search_compiles_for_four_chips(one_chip):
     """chip_smoke.py --chips 4's program: a 4-shard index, one shard per
     chip of the 2x2 mesh, searched by shard_map with the hierarchical
